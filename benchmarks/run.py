@@ -97,6 +97,8 @@ def _append_history(names, failed, dt: float) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = sys.argv[1:] or list(ALL)
     t0 = time.time()
     failed = []

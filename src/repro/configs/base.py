@@ -90,8 +90,6 @@ class ArchConfig:
     matmul_backend: str = ""             # "" = resolve from the flags above;
     #                                      explicit: bf16 | qat | photonic_sim
     #                                      | photonic_pallas (core/backend.py)
-    pallas_interpret: bool = True        # run Pallas kernels in interpreter
-    #                                      mode (CPU hosts); False on TPU
     attn_backend: str = ""               # attention-core dispatch: "" -> xla
     #                                      (materialized scores) | flash
     #                                      (fused RoI-masked Pallas kernel,

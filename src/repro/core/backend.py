@@ -74,6 +74,7 @@ from repro.core import quant
 
 __all__ = [
     "ExecPolicy",
+    "interpret_default",
     "QuantizedWeight",
     "quantize_weight",
     "prepare_params",
@@ -108,8 +109,11 @@ class ExecPolicy:
     flags resolve it: photonic -> photonic_sim, quant_bits -> qat, else bf16.
     ``attn_backend`` names an attention-core registry entry ("" -> xla);
     ``ffn_backend`` an FFN registry entry ("" -> xla).
-    ``interpret`` runs Pallas kernels in interpreter mode (CPU hosts); set
-    False on a real TPU deployment.
+    ``interpret`` runs Pallas kernels in interpreter mode. Left unset it
+    follows the platform the program runs on (``interpret_default``,
+    asked when first read, so building a policy never starts a backend):
+    the interpreter on CPU hosts, compiled kernels everywhere else. Tests
+    that must steer it pass or assign it explicitly.
     ``bit_plan`` is the hashable identity of the active mixed-precision
     plan (``core.bitalloc.plan_key`` output, or a bare per-layer tuple) —
     None means uniform ``quant_bits``. Setting it does two things: the
@@ -120,12 +124,12 @@ class ExecPolicy:
     """
 
     __slots__ = ("quant_bits", "photonic", "training", "dot_out_native",
-                 "backend", "interpret", "attn_backend", "ffn_backend",
+                 "backend", "_interpret", "attn_backend", "ffn_backend",
                  "bit_plan", "noise")
 
     def __init__(self, quant_bits: int = 0, photonic: bool = False,
                  training: bool = True, dot_out_native: bool = False,
-                 backend: str = "", interpret: bool = True,
+                 backend: str = "", interpret: bool | None = None,
                  attn_backend: str = "", ffn_backend: str = "",
                  bit_plan=None, noise=None):
         self.quant_bits = quant_bits
@@ -133,7 +137,7 @@ class ExecPolicy:
         self.training = training
         self.dot_out_native = dot_out_native
         self.backend = backend
-        self.interpret = interpret
+        self._interpret = interpret
         self.attn_backend = attn_backend
         self.ffn_backend = ffn_backend
         self.bit_plan = (tuple(bit_plan) if isinstance(bit_plan, list)
@@ -143,13 +147,23 @@ class ExecPolicy:
         # built before the field existed
         self.noise = noise
 
+    @property
+    def interpret(self) -> bool:
+        if self._interpret is None:
+            self._interpret = interpret_default()
+        return self._interpret
+
+    @interpret.setter
+    def interpret(self, value: bool) -> None:
+        self._interpret = value
+
     @staticmethod
     def from_cfg(cfg, training: bool = True) -> "ExecPolicy":
         return ExecPolicy(getattr(cfg, "quant_bits", 0),
                           getattr(cfg, "photonic", False), training,
                           getattr(cfg, "dot_out_native", False),
                           getattr(cfg, "matmul_backend", "") or "",
-                          getattr(cfg, "pallas_interpret", True),
+                          None,
                           getattr(cfg, "attn_backend", "") or "",
                           getattr(cfg, "ffn_backend", "") or "",
                           getattr(cfg, "bit_plan", None) or None,
@@ -209,7 +223,11 @@ class ExecPolicy:
                 f"bits={self.quant_bits}, training={self.training}{noise})")
 
 
-_DEFAULT = ExecPolicy()
+def interpret_default() -> bool:
+    """Whether Pallas kernels must run in the interpreter: only where JAX's
+    default backend is the CPU. The one place the execution mode is
+    decided — on a TPU every kernel compiles, never silently emulated."""
+    return jax.default_backend() == "cpu"
 
 
 # --------------------------------------------------------------------------
@@ -735,7 +753,7 @@ def matmul(x: jnp.ndarray, w, policy: ExecPolicy | None = None) -> jnp.ndarray:
 
     x: (..., d_in); w: (d_in, d_out) array or cached ``QuantizedWeight``.
     """
-    p = policy or _DEFAULT
+    p = policy or ExecPolicy()
     if p.noise is not None:
         return _noisy_matmul(x, w, p)
     return get_backend(p.resolve_backend())(x, w, p)
@@ -849,7 +867,7 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     the photonic hardware), so they stay float on every matmul backend;
     only *which dataflow computes them* is dispatched here.
     """
-    p = policy or _DEFAULT
+    p = policy or ExecPolicy()
     if mask is not None and kv_len is not None:
         raise ValueError("give mask or kv_len, not both")
     if scale is None:
@@ -967,6 +985,6 @@ def ffn(x: jnp.ndarray, w1, b1: jnp.ndarray, w2, b2: jnp.ndarray,
     axis on skipping backends (key j live iff j < live_rows — the packed
     one-shape serving layout); the xla reference computes every row.
     """
-    p = policy or _DEFAULT
+    p = policy or ExecPolicy()
     return get_ffn_backend(p.resolve_ffn_backend())(x, w1, b1, w2, b2, p,
                                                     live_rows)

@@ -20,7 +20,8 @@ for the bucketed ladder), applies it inside the streaming-softmax update,
 and skips KV blocks whose keys are *all* pruned — so non-RoI patches cost
 zero score FLOPs instead of being masked after the full (Sq, Skv) compute
 is paid. The per-(batch, kv-block) live counts are reduced once on the
-XLA side and read from SMEM, mirroring flash_decode's ``len_ref``.
+XLA side and scalar-prefetched into SMEM, so the skip predicate is a
+scalar read per grid step.
 
 Validated in interpret mode against kernels/ref.py::flash_attention_ref
 over shape/dtype/mask sweeps (tests/test_kernels_flash.py and the
@@ -156,17 +157,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def flash_attention_masked_kernel(nlive_ref, q_ref, k_ref, v_ref, mask_ref,
                                   o_ref, m_ref, l_ref, acc_ref, *,
-                                  scale: float):
+                                  scale: float, heads: int):
     """One (bq, bkv) tile of key-masked bidirectional flash attention.
 
-    ``nlive_ref`` (SMEM) holds the number of unmasked keys in this KV
-    block; when zero the whole tile is skipped — no score dot, no softmax
-    update, no PV dot. Inside a live tile masked keys get ``NEG_INF``
-    scores so they carry exactly-zero probability weight. A live tile has
-    >= 1 unmasked key, so every row max stays finite and the classic
-    ``exp(NEG_INF - NEG_INF)`` poisoning cannot occur.
+    ``nlive_ref`` is the scalar-prefetched (B, nkv) table of unmasked-key
+    counts per KV block; when this block's count is zero the whole tile is
+    skipped — no score dot, no softmax update, no PV dot. Inside a live
+    tile masked keys get ``NEG_INF`` scores so they carry exactly-zero
+    probability weight. A live tile has >= 1 unmasked key, so every row
+    max stays finite and the classic ``exp(NEG_INF - NEG_INF)`` poisoning
+    cannot occur.
     """
     ki = pl.program_id(2)
+    nlive = nlive_ref[pl.program_id(0) // heads, ki]
 
     @pl.when(ki == 0)
     def _init():
@@ -174,13 +177,13 @@ def flash_attention_masked_kernel(nlive_ref, q_ref, k_ref, v_ref, mask_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(nlive_ref[0, 0] > 0)
+    @pl.when(nlive > 0)
     def _compute():
         q = q_ref[0].astype(jnp.float32) * scale          # (bq, D)
         k = k_ref[0].astype(jnp.float32)                  # (bkv, D)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s = jnp.where(mask_ref[...] > 0, s, NEG_INF)      # (1, bkv) bcast
+        s = jnp.where(mask_ref[0] > 0, s, NEG_INF)        # (1, bkv) bcast
 
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -233,9 +236,9 @@ def flash_attention_masked(q: jax.Array, k: jax.Array, v: jax.Array,
     per batch row; ``kv_len`` (scalar or (B,)) is the packed alternative
     for the bucketed path — key j is kept iff j < kv_len. Give at most
     one. KV blocks with no kept key are skipped inside the kernel
-    (``pl.when`` on an SMEM live-count), so a 50%-pruned packed stream
-    pays ~50% of the score/PV FLOPs. ``scale`` defaults to 1/sqrt(D);
-    pass 1.0 when the scale is already folded into Q (Eq. 2).
+    (``pl.when`` on a scalar-prefetched live count), so a 50%-pruned
+    packed stream pays ~50% of the score/PV FLOPs. ``scale`` defaults to
+    1/sqrt(D); pass 1.0 when the scale is already folded into Q (Eq. 2).
 
     Sq/Skv need not be block multiples: both are padded (padded keys are
     masked out, padded query rows sliced off). Rows with zero live keys
@@ -263,36 +266,46 @@ def flash_attention_masked(q: jax.Array, k: jax.Array, v: jax.Array,
     maskp = _pad_axis(key_mask.astype(jnp.float32), 1, bkv)
     sqp, skvp = qp.shape[2], kp.shape[2]
     nkv = skvp // bkv
-    # per-(batch, kv-block) live-key counts — the block-skip predicate
+    # per-(batch, kv-block) live-key counts — the block-skip predicate,
+    # scalar-prefetched into SMEM whole (a per-step (1, 1) block of a
+    # (B, nkv) array breaks the TPU's (8, 128) block tiling rule)
     nlive = maskp.reshape(b, nkv, bkv).sum(-1).astype(jnp.int32)
+    # the mask gets a unit middle axis so its (1, bkv) block trails with
+    # dims the tiling rule accepts (1 = full extent, bkv = 128 or full)
+    mask3 = maskp.reshape(b, 1, skvp)
 
     gk, gv = h // hk, h // hv
     qf = qp.reshape(b * h, sqp, d)
     kf = kp.reshape(b * hk, skvp, d)
     vf = vp.reshape(b * hv, skvp, dv)
 
-    grid = (b * h, sqp // bq, nkv)
-    kern = functools.partial(flash_attention_masked_kernel, scale=scale)
-    out = pl.pallas_call(
-        kern,
-        grid=grid,
+    kern = functools.partial(flash_attention_masked_kernel, scale=scale,
+                             heads=h)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b * h, sqp // bq, nkv),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, qi, ki, h=h: (i // h, ki),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, bq, d), lambda i, qi, ki: (i, qi, 0)),
+            pl.BlockSpec((1, bq, d), lambda i, qi, ki, nl: (i, qi, 0)),
             pl.BlockSpec((1, bkv, d),
-                         lambda i, qi, ki, gk=gk: (i // gk, ki, 0)),
+                         lambda i, qi, ki, nl, gk=gk: (i // gk, ki, 0)),
             pl.BlockSpec((1, bkv, dv),
-                         lambda i, qi, ki, gv=gv: (i // gv, ki, 0)),
-            pl.BlockSpec((1, bkv), lambda i, qi, ki, h=h: (i // h, ki)),
+                         lambda i, qi, ki, nl, gv=gv: (i // gv, ki, 0)),
+            pl.BlockSpec((1, 1, bkv),
+                         lambda i, qi, ki, nl, h=h: (i // h, 0, ki)),
         ],
-        out_specs=pl.BlockSpec((1, bq, dv), lambda i, qi, ki: (i, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sqp, dv), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, dv),
+                               lambda i, qi, ki, nl: (i, qi, 0)),
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, dv), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * h, sqp, dv), q.dtype),
         interpret=interpret,
-    )(nlive, qf, kf, vf, maskp)
+        name="flash_attention_masked",
+    )(nlive, qf, kf, vf, mask3)
     return out.reshape(b, h, sqp, dv)[:, :, :sq]
 
 
@@ -348,7 +361,7 @@ def fused_masked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                            kv_len: jax.Array | int | None = None,
                            scale: float | None = None,
                            bq: int = 128, bkv: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """The RoI-masked attention core, lowered for the host it runs on:
     the Pallas kernel when compiling for TPU (``interpret=False``), the
     XLA twin on CPU hosts. Both implement the identical contract

@@ -228,6 +228,7 @@ def fused_ffn_int8(x: jax.Array, w1q: jax.Array, sw1: jax.Array,
         out_shape=jax.ShapeDtypeStruct((xq.shape[0], doutp), jnp.float32),
         scratch_shapes=[pltpu.SMEM((1, 1), jnp.float32)],
         interpret=interpret,
+        name="fused_ffn",
     )(xq, sx.reshape(1, 1), w1p, sw1p, b1p, w2p, sw2p)
     y = out[:m, :dout].astype(x.dtype) + b2
     return _restore_dead(y.reshape(*lead, dout), n_tokens)
@@ -240,8 +241,8 @@ def _dequant_epilogue_kernel(acc_ref, sx_ref, sw_ref, o_ref):
                   * sw_ref[0, :][None, :])
 
 
-def _dequant_epilogue(acc: jax.Array, sx: jax.Array,
-                      sw: jax.Array) -> jax.Array:
+def _dequant_epilogue(acc: jax.Array, sx: jax.Array, sw: jax.Array, *,
+                      interpret: bool) -> jax.Array:
     """Dequantize (M, N) int32 -> f32 through a two-block Pallas walk.
 
     Running the epilogue as a (gridded) kernel is a numerics requirement,
@@ -254,9 +255,15 @@ def _dequant_epilogue(acc: jax.Array, sx: jax.Array,
     The grid loop is the same fusion boundary the reference has; two row
     blocks keep it a loop at every M (a single-step grid lowers to
     straight-line HLO that XLA sees through).
+
+    On CPU (``interpret=True``) this is the bit-parity anchor above. On
+    TPU the same walk compiles, with each row block rounded up to the
+    8-row sublane tile the chip's block rule asks for.
     """
     m, n = acc.shape
     bm = -(-m // 2)
+    if not interpret:
+        bm = -(-bm // 8) * 8
     accp = _pad_axis(acc, 0, 2 * bm)
     out = pl.pallas_call(
         _dequant_epilogue_kernel,
@@ -268,7 +275,8 @@ def _dequant_epilogue(acc: jax.Array, sx: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((2 * bm, n), jnp.float32),
-        interpret=True,
+        interpret=interpret,
+        name="dequant_epilogue",
     )(accp, sx.reshape(1, 1), sw.reshape(1, -1))
     return out[:m]
 
@@ -284,7 +292,7 @@ def _int8_linear_xla(x2: jax.Array, wq: jax.Array, sw: jax.Array, *,
     acc = jax.lax.dot_general(xq.astype(jnp.int32), wq.astype(jnp.int32),
                               (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.int32)
-    return _dequant_epilogue(acc, sx, sw)
+    return _dequant_epilogue(acc, sx, sw, interpret=True)
 
 
 def fused_ffn_xla(x: jax.Array, w1q: jax.Array, sw1: jax.Array,
@@ -320,7 +328,7 @@ def fused_ffn_xla(x: jax.Array, w1q: jax.Array, sw1: jax.Array,
 
 
 def _int8_linear_sharded(x2: jax.Array, wq: jax.Array, sw: jax.Array, *,
-                         bits: int, scale_axes,
+                         bits: int, scale_axes, interpret: bool,
                          psum_axis: str | None = None) -> jax.Array:
     """``_int8_linear_xla`` for use *inside* ``shard_map``: the activation
     absmax scale is pmax'd over ``scale_axes`` (so every shard quantizes
@@ -340,15 +348,16 @@ def _int8_linear_sharded(x2: jax.Array, wq: jax.Array, sw: jax.Array, *,
                               preferred_element_type=jnp.int32)
     if psum_axis is not None:
         acc = exact_int_psum(acc, psum_axis)
-    return _dequant_epilogue(acc, sx, sw)
+    return _dequant_epilogue(acc, sx, sw, interpret=interpret)
 
 
 def fused_ffn_sharded(x: jax.Array, w1q: jax.Array, sw1: jax.Array,
                       b1: jax.Array, w2q: jax.Array, sw2: jax.Array,
                       b2: jax.Array, *, bits=8,
                       live_rows: int | None = None,
-                      model_axis: str = "model",
-                      scale_axes=("data", "model")) -> jax.Array:
+                      model_axis: str | None = "model",
+                      scale_axes=("data", "model"),
+                      interpret: bool) -> jax.Array:
     """``fused_ffn_xla`` under ``shard_map`` over the d_ff (model) axis.
 
     Per-shard operands: w1q (d_in, d_ff/M) columns + sw1/b1 (d_ff/M,),
@@ -361,7 +370,11 @@ def fused_ffn_sharded(x: jax.Array, w1q: jax.Array, sw1: jax.Array,
     the Pallas dequant epilogue (the FMA fusion boundary), so the result
     is bitwise-equal to ``fused_ffn_xla`` on the gathered operands.
     ``scale_axes`` must name every mesh axis the token rows are split
-    over *plus* the model axis (batch-sharded callers pass both)."""
+    over *plus* the model axis (batch-sharded callers pass both). On a
+    data-only mesh ``model_axis=None``: each shard holds all of d_ff and
+    there is no partial sum to reduce.
+    ``interpret`` is the caller's ``ExecPolicy.interpret``: it picks the
+    CPU or TPU form of the dequant epilogue."""
     bits1, bits2 = _bits_pair(bits)
     n_tokens = x.shape[-2]
     xl, lv = _slice_live(x, live_rows)
@@ -370,11 +383,11 @@ def fused_ffn_sharded(x: jax.Array, w1q: jax.Array, sw1: jax.Array,
     lead = xl.shape[:-1]
     dout = w2q.shape[1]
     x2 = xl.reshape(-1, x.shape[-1]).astype(jnp.float32)
-    h = _int8_linear_sharded(x2, w1q, sw1, bits=bits1,
-                             scale_axes=scale_axes).astype(x.dtype) + b1
+    h = _int8_linear_sharded(x2, w1q, sw1, bits=bits1, scale_axes=scale_axes,
+                             interpret=interpret).astype(x.dtype) + b1
     g = jax.nn.gelu(h.astype(jnp.float32)).astype(x.dtype)
     y = _int8_linear_sharded(g.astype(jnp.float32), w2q, sw2, bits=bits2,
-                             scale_axes=scale_axes,
+                             scale_axes=scale_axes, interpret=interpret,
                              psum_axis=model_axis).astype(x.dtype) + b2
     return _restore_dead(y.reshape(*lead, dout), n_tokens)
 
@@ -382,7 +395,7 @@ def fused_ffn_sharded(x: jax.Array, w1q: jax.Array, sw1: jax.Array,
 def fused_ffn(x: jax.Array, w1q: jax.Array, sw1: jax.Array, b1: jax.Array,
               w2q: jax.Array, sw2: jax.Array, b2: jax.Array, *,
               bits=8, live_rows: int | None = None, bm: int = 128,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool) -> jax.Array:
     """The fused int8 FFN, lowered for the host it runs on: the Pallas
     kernel when compiling for TPU (``interpret=False``), the XLA twin on
     CPU hosts (the serving hot path's FFN entry point, dispatched by

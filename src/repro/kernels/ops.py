@@ -5,8 +5,12 @@ symmetric — core/quant.py), pads to kernel block multiples, runs the int8
 kernel and dequantizes. ``fused_attention`` exposes the flash kernel with
 the models/attention.py calling convention (B, S, H, D).
 
-Both take ``interpret=`` so tests run the kernel body on CPU; on a real
-TPU deployment set interpret=False (config flag ``use_pallas``).
+Every wrapper takes ``interpret=``: the Pallas interpreter on CPU hosts,
+compiled kernels on TPU. The serving path passes ``ExecPolicy.interpret``,
+which follows the platform (``core.backend.interpret_default``). The
+serving entry points (``fused_roi_attention_prequant`` here,
+``fused_masked_attention``, ``fused_ffn``) have no default for it, so none
+of them can silently emulate on a chip.
 """
 
 from __future__ import annotations
@@ -132,7 +136,7 @@ def fused_roi_attention_prequant(x: jax.Array,
                                  heads: int, kv_len: int | None = None,
                                  bits=8,
                                  bq: int = 128, bkv: int = 128,
-                                 interpret: bool = True) -> jax.Array:
+                                 interpret: bool) -> jax.Array:
     """The serving hot path in one jit: int8 cached-weight QKV projections
     (``photonic_matmul_prequant`` x3 — the quantize-once cache's tuned MR
     banks) feeding the fused RoI-masked flash kernel.

@@ -86,4 +86,5 @@ def photonic_matmul_int8(xq: jax.Array, wq: jax.Array, sx: jax.Array,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="photonic_matmul",
     )(xq, wq, sx.reshape(1, 1), sw.reshape(1, n))

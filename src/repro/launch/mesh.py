@@ -13,15 +13,23 @@ Meshes (pinned by the assignment):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_host_mesh", "make_serving_mesh",
            "batch_shard_count"]
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: GSPMD propagates the
+    shardings, and gathers and slices of sharded arrays stay legal (the
+    ``Explicit`` default would raise ``ShardingTypeError`` on them)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -29,7 +37,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // data))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_serving_mesh(model: int = 1):
@@ -54,10 +62,10 @@ def make_serving_mesh(model: int = 1):
         if n % model != 0:
             raise ValueError(f"device count {n} is not divisible by "
                              f"model={model}")
-        return jax.make_mesh((n // model, model), ("data", "model"))
+        return _auto_mesh((n // model, model), ("data", "model"))
     if n < 2:
         return None
-    return jax.make_mesh((n,), ("data",))
+    return _auto_mesh((n,), ("data",))
 
 
 def batch_shard_count(mesh) -> int:

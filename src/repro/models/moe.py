@@ -21,13 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                     # jax >= 0.6: top-level API
-    _shard_map = jax.shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except AttributeError:                   # jax 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
 from repro.distributed.sharding import current_ctx, shard
 from repro.models.layers import ExecPolicy, he_init
 from repro.models import ffn as ffn_mod
@@ -292,13 +285,13 @@ def moe_ffn_shard_map(params: dict, x: jnp.ndarray, *, top_k: int,
         return y.astype(x_loc.dtype).reshape(bl, s, d), aux
 
     x_spec = P(batch_rule, None, None)
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None),
                   P("model", embed_rule, None), P("model", embed_rule, None),
                   P("model", None, embed_rule)),
         out_specs=(x_spec, P()),
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )(x, params["router"], params["w_gate"], params["w_up"],
       params["w_down"])
 

@@ -1,11 +1,15 @@
-"""Model-sharded single-jit encoder: the fused serving hot path under
-``shard_map`` over a 2-D ("data", "model") mesh.
+"""Sharded single-jit encoder: the fused serving hot path under
+``shard_map`` over a serving mesh — 2-D ("data", "model") or data-only
+("data",).
 
 The fused encoder in models/vit.py runs the whole trunk as one jit —
 fused RoI attention + fused int8 FFN scanned over the stacked layer
 weights. This module re-traces exactly that graph *inside* one
-``shard_map`` so big ViT variants whose weights (or activations) outgrow
-one device keep the single-dispatch serving path:
+``shard_map``. On a TPU that is the only way the encoder runs on more
+than one chip at all: XLA cannot partition a Pallas (Mosaic) kernel, so
+every kernel must sit in a region where each device's shard is explicit.
+A "model" axis additionally lets big ViT variants whose weights (or
+activations) outgrow one device keep the single-dispatch serving path:
 
   * attention heads are embarrassingly parallel: wq/wk/wv **column-shard**
     over "model" (output columns are head-major), each shard runs the
@@ -49,7 +53,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -65,12 +68,8 @@ from repro.models.layers import ExecPolicy, layernorm
 __all__ = ["sharded_encode", "sharded_encode_ineligible_reason",
            "sharded_encoder_cache_size"]
 
-_SCALE_AXES = ("data", "model")
-
-
 def _pallas_proj(x2: jnp.ndarray, wq: jnp.ndarray, sw: jnp.ndarray, *,
-                 bits: int, interpret: bool,
-                 scale_axes=_SCALE_AXES) -> jnp.ndarray:
+                 bits: int, interpret: bool, scale_axes) -> jnp.ndarray:
     """``ops.photonic_matmul_prequant`` inlined for use inside
     ``shard_map``: same quantize -> pad -> ``photonic_matmul_int8`` (with
     its in-kernel dequant) dataflow, with the per-launch activation absmax
@@ -118,12 +117,12 @@ def sharded_encode_ineligible_reason(params: dict, cfg: ArchConfig,
         return "no sharding context installed"
     mesh = ctx.mesh
     axes = tuple(mesh.axis_names)
-    if axes != ("data", "model"):
-        return (f"mesh axes {axes!r} are not the 2-D ('data', 'model') "
-                f"serving layout (launch.mesh.make_serving_mesh(model=M))")
-    m = mesh.shape["model"]
-    if m < 2:
-        return "model axis has size 1 — nothing to shard"
+    if axes not in (("data",), ("data", "model")):
+        return (f"mesh axes {axes!r} are not a serving layout: ('data',) "
+                f"or ('data', 'model') (launch.mesh.make_serving_mesh)")
+    if mesh.size < 2:
+        return "the mesh has one device — nothing to shard"
+    m = _model_shards(mesh)
     if cfg.n_heads % m:
         return (f"n_heads={cfg.n_heads} not divisible by the model axis "
                 f"({m}) — heads cannot split evenly")
@@ -135,6 +134,11 @@ def sharded_encode_ineligible_reason(params: dict, cfg: ArchConfig,
     except ValueError as e:
         return str(e)
     return None
+
+
+def _model_shards(mesh) -> int:
+    """Size of the mesh's "model" axis; 1 on a data-only mesh."""
+    return mesh.shape["model"] if "model" in mesh.axis_names else 1
 
 
 def _qw_dict(w, bits: int) -> dict:
@@ -172,15 +176,16 @@ def _enc_tree(params: dict, bits: dict[str, int]) -> dict:
     }
 
 
-def _enc_specs() -> dict:
+def _enc_specs(model: str | None) -> dict:
     """in_specs tree matching ``_enc_tree``: head-major output columns
-    (wq/wk/wv, w1) shard over "model", the w2 contraction rows (= d_ff)
-    shard over "model" with replicated output scales, and everything else
-    — including wo, whose in-kernel dequant forbids a row split (module
-    docstring) — replicates. Mirrors what MODEL_RULES + vit_logical_axes
-    place on the devices, so the dispatch edge moves no bytes."""
-    col = P(None, None, "model")       # stacked codes/scales, cols = heads
-    row = P(None, "model", None)       # stacked codes, rows = d_ff
+    (wq/wk/wv, w1) shard over the ``model`` axis, the w2 contraction rows
+    (= d_ff) shard over it with replicated output scales, and everything
+    else — including wo, whose in-kernel dequant forbids a row split
+    (module docstring) — replicates. Mirrors what MODEL_RULES +
+    vit_logical_axes place on the devices, so the dispatch edge moves no
+    bytes. ``model=None`` (a data-only mesh) replicates every weight."""
+    col = P(None, None, model)         # stacked codes/scales, cols = heads
+    row = P(None, model, None)         # stacked codes, rows = d_ff
     rep3 = P(None, None, None)
     rep2 = P(None, None)
     return {
@@ -193,7 +198,7 @@ def _enc_specs() -> dict:
                      "wo": {"wq": rep3, "scale": rep3}},
             "ln2_g": rep2, "ln2_b": rep2,
             "ffn": {"w1": {"wq": col, "scale": col},
-                    "b1": P(None, "model"),
+                    "b1": P(None, model),
                     "w2": {"wq": row, "scale": rep3},
                     "b2": rep2},
         },
@@ -218,7 +223,9 @@ def _build_jit(cfg: ArchConfig, policy: ExecPolicy, bits: dict[str, int],
                kv_len: int | None, has_mask: bool, mesh,
                batch_sharded: bool):
     n_heads, d, eps = cfg.n_heads, cfg.d_model, cfg.norm_eps
-    m_shards = mesh.shape["model"]
+    model = "model" if "model" in mesh.axis_names else None
+    scale_axes = tuple(mesh.axis_names)
+    m_shards = _model_shards(mesh)
     h_loc = n_heads // m_shards
     dh = d // n_heads
     d_loc = h_loc * dh
@@ -244,7 +251,8 @@ def _build_jit(cfg: ArchConfig, policy: ExecPolicy, bits: dict[str, int],
             for name in ("wq", "wk", "wv"):
                 wd = lp["attn"][name]
                 y = _pallas_proj(x2, wd["wq"], wd["scale"].reshape(-1),
-                                 bits=bits[name], interpret=interpret)
+                                 bits=bits[name], interpret=interpret,
+                                 scale_axes=scale_axes)
                 qkv.append(y.reshape(b, n, d_loc).astype(h.dtype)
                            .reshape(b, n, h_loc, dh).transpose(0, 2, 1, 3))
             o = fused_masked_attention(qkv[0], qkv[1], qkv[2], kmask,
@@ -253,11 +261,13 @@ def _build_jit(cfg: ArchConfig, policy: ExecPolicy, bits: dict[str, int],
             # exact data movement: every shard assembles the full
             # head-major (b, n, d) activation, then runs the whole wo
             # projection (in-kernel dequant — see module docstring)
-            full = jax.lax.all_gather(merged, "model", axis=2, tiled=True)
+            full = (merged if model is None else
+                    jax.lax.all_gather(merged, model, axis=2, tiled=True))
             wd = lp["attn"]["wo"]
             ao = _pallas_proj(full.astype(jnp.float32).reshape(-1, d),
                               wd["wq"], wd["scale"].reshape(-1),
-                              bits=bits["wo"], interpret=interpret)
+                              bits=bits["wo"], interpret=interpret,
+                              scale_axes=scale_axes)
             carry = carry + ao.reshape(b, n, d).astype(h.dtype) \
                               .astype(carry.dtype)
             h2 = layernorm(carry, lp["ln2_g"], lp["ln2_b"], eps)
@@ -267,7 +277,8 @@ def _build_jit(cfg: ArchConfig, policy: ExecPolicy, bits: dict[str, int],
                 lp["ffn"]["w2"]["wq"],
                 lp["ffn"]["w2"]["scale"].reshape(-1), lp["ffn"]["b2"],
                 bits=(bits["w1"], bits["w2"]), live_rows=ffn_live,
-                model_axis="model", scale_axes=_SCALE_AXES)
+                model_axis=model, scale_axes=scale_axes,
+                interpret=interpret)
             return carry + f, None
 
         fn = jax.checkpoint(step) if cfg.remat else step
@@ -276,25 +287,26 @@ def _build_jit(cfg: ArchConfig, policy: ExecPolicy, bits: dict[str, int],
         logits = _pallas_proj(x[:, 0].astype(jnp.float32),
                               enc["head"]["wq"],
                               enc["head"]["scale"].reshape(-1),
-                              bits=bits["head"], interpret=interpret)
+                              bits=bits["head"], interpret=interpret,
+                              scale_axes=scale_axes)
         return logits.astype(x.dtype)
 
     tok_spec = P("data", None, None) if batch_sharded else P(None, None, None)
     out_spec = P("data", None) if batch_sharded else P(None, None)
     mask_spec = P("data", None) if batch_sharded else P(None, None)
-    specs = _enc_specs()
+    specs = _enc_specs(model)
 
     if has_mask:
-        smapped = shard_map(body, mesh=mesh,
-                            in_specs=(specs, tok_spec, mask_spec),
-                            out_specs=out_spec, check_rep=False)
+        smapped = jax.shard_map(body, mesh=mesh,
+                                in_specs=(specs, tok_spec, mask_spec),
+                                out_specs=out_spec, check_vma=False)
 
         def run(params, tokens, patch_mask):
             return smapped(_enc_tree(params, bits), tokens, patch_mask)
     else:
-        smapped = shard_map(lambda enc, t: body(enc, t, None), mesh=mesh,
-                            in_specs=(specs, tok_spec),
-                            out_specs=out_spec, check_rep=False)
+        smapped = jax.shard_map(lambda enc, t: body(enc, t, None),
+                                mesh=mesh, in_specs=(specs, tok_spec),
+                                out_specs=out_spec, check_vma=False)
 
         def run(params, tokens, patch_mask):
             return smapped(_enc_tree(params, bits), tokens)

@@ -362,11 +362,11 @@ def encode_tokens(params: dict, tokens: jnp.ndarray, cfg: ArchConfig,
     reason = _fused_encoder_ineligible_reason(params, cfg, policy)
     if reason is None:
         ctx = current_ctx()
-        if ctx is not None and "model" in ctx.mesh.axis_names \
-                and ctx.mesh.shape["model"] > 1:
-            # 2-D serving mesh: try the model-sharded twin of the fused
-            # jit (same graph under shard_map — bitwise-equal logits);
-            # ineligible combos warn once and keep the unsharded jit.
+        if ctx is not None and ctx.mesh.size > 1:
+            # serving mesh: the sharded twin of the fused jit (same graph
+            # under shard_map — bitwise-equal logits; the only form whose
+            # Pallas kernels a TPU can run on several chips); ineligible
+            # combos warn once and keep the unsharded jit.
             from repro.models import sharded_encoder
             sreason = sharded_encoder.sharded_encode_ineligible_reason(
                 params, cfg, policy, ctx)

@@ -38,7 +38,7 @@ import re
 from dataclasses import dataclass, field
 
 __all__ = ["Cost", "HloModule", "parse_hlo", "analyze_module",
-           "compile_and_cost", "collective_summary"]
+           "compile_and_cost", "collective_summary", "tpu_kernel_counts"]
 
 _ESIZE = {"f64": 8, "s64": 8, "u64": 8, "c64": 8,
           "f32": 4, "s32": 4, "u32": 4,
@@ -569,3 +569,21 @@ def collective_summary(cost: Cost) -> str:
         return "none"
     return ", ".join(f"{k}={v / 1e6:.1f}MB"
                      for k, v in sorted(cost.coll_by_op.items()))
+
+
+_KERNEL_NAME = re.compile(r'/(\w+)/pallas_call"')
+
+
+def tpu_kernel_counts(hlo_text: str) -> dict[str, int]:
+    """{kernel name: compiled Pallas TPU custom calls} in an optimized HLO
+    text — each ``tpu_custom_call`` carries its ``pallas_call(name=...)``
+    in its op_name metadata. Empty for interpret-mode or CPU programs,
+    which lower the kernels to plain HLO."""
+    counts: dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = _KERNEL_NAME.search(line)
+        name = m.group(1) if m else "?"
+        counts[name] = counts.get(name, 0) + 1
+    return counts

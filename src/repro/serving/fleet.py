@@ -19,14 +19,13 @@ answers with the control-plane pieces the serving stack already grew:
   * **drain(i)** retires a worker via ``checkpoint``/``restore_checkpoint``
     into a fresh replacement, preserving every queued session.
 
-Workers are in-process by default — they share one prepared int8 weight
-cache (``prepare_params`` is idempotent, so only worker 0 pays MR
-tuning) and serve sequentially, each on its own measured wall, so
-aggregate fps is ``total_frames / max(worker walls)`` — the N-host
-model where walls overlap. ``spawn=True`` runs each worker's serve in a
-real ``multiprocessing`` spawn process instead (own JAX runtime, own
-compiles — the honest multi-host cost model); migration and drain need
-shared address space and raise under spawn.
+Workers live in the router's process — a TPU host gives each chip to
+one process, so they are never forked off into processes of their own.
+They share one prepared int8 weight cache (``prepare_params`` is
+idempotent, so only worker 0 pays MR tuning) and serve sequentially,
+each on its own measured wall, so aggregate fps is
+``total_frames / max(worker walls)`` — the N-host model where walls
+overlap.
 
 Dead-bucket accounting warnings are aggregated here: workers serve with
 per-session warnings muted and the router emits ONE ``UserWarning``
@@ -38,7 +37,7 @@ this caller).
 CLI::
 
     PYTHONPATH=src python -m repro.serving.fleet --workers 4
-    PYTHONPATH=src python -m repro.serving.fleet --workers 2 --spawn \
+    PYTHONPATH=src python -m repro.serving.fleet --workers 2 \
         --streams 6 --frames 32 --placement rr
 """
 
@@ -90,7 +89,7 @@ class FleetRouter:
 
     def __init__(self, cfg, server_cfg: ServerConfig | None = None,
                  workers: int = 4, placement: str = "cost",
-                 n_classes: int = 10, seed: int = 0, spawn: bool = False,
+                 n_classes: int = 10, seed: int = 0,
                  price_per_frame: float | None = None):
         if workers < 1:
             raise ValueError("need at least one worker")
@@ -102,20 +101,17 @@ class FleetRouter:
         self.placement = placement
         self.n_classes = n_classes
         self.seed = seed
-        self.spawn = spawn
-        self.workers: list[StreamServer] = []
-        if not spawn:
-            first = StreamServer(cfg, self.server_cfg,
-                                 n_classes=n_classes, seed=seed)
-            self.workers.append(first)
-            for _ in range(workers - 1):
-                # share the tuned cache: prepare_params is idempotent on
-                # QuantizedWeight leaves, so only worker 0 pays MR tuning
-                self.workers.append(StreamServer(
-                    cfg, self.server_cfg, params=first.params,
-                    n_classes=n_classes, seed=seed))
-            for i, w in enumerate(self.workers):
-                w._next_sid = i * _SID_STRIDE
+        first = StreamServer(cfg, self.server_cfg,
+                             n_classes=n_classes, seed=seed)
+        self.workers: list[StreamServer] = [first]
+        for _ in range(workers - 1):
+            # share the tuned cache: prepare_params is idempotent on
+            # QuantizedWeight leaves, so only worker 0 pays MR tuning
+            self.workers.append(StreamServer(
+                cfg, self.server_cfg, params=first.params,
+                n_classes=n_classes, seed=seed))
+        for i, w in enumerate(self.workers):
+            w._next_sid = i * _SID_STRIDE
         self.n_workers = workers
         self.jobs: dict[int, FleetJob] = {}
         self._next_job = 0
@@ -133,8 +129,6 @@ class FleetRouter:
         return self._price
 
     def _price_from_cost_model(self) -> float:
-        if self.spawn or not self.workers:
-            return 1.0
         try:
             from repro.serving.control.costmodel import EncodeCostModel
             w0 = self.workers[0]
@@ -170,17 +164,13 @@ class FleetRouter:
 
     def add_job(self, stream, n_frames: int = 64, start: int = 0) -> FleetJob:
         """Place one stream: pick a worker, register the session there
-        (in-process mode) and record the job. Returns the ``FleetJob``."""
+        and record the job. Returns the ``FleetJob``."""
         cost = n_frames * self.price_per_frame()
         widx = self._pick_worker(cost)
-        if self.spawn:
-            sid = self._next_job + _SID_STRIDE * widx   # assigned in-child
-        else:
-            s = self.workers[widx].add_session(stream, n_frames=n_frames,
-                                               start=start)
-            sid = s.sid
+        s = self.workers[widx].add_session(stream, n_frames=n_frames,
+                                           start=start)
         job = FleetJob(self._next_job, stream, int(n_frames), int(start),
-                       widx, sid, cost)
+                       widx, s.sid, cost)
         self.jobs[job.job_id] = job
         self._next_job += 1
         return job
@@ -197,8 +187,6 @@ class FleetRouter:
         Per-session dead-bucket warnings are muted; the router emits one
         aggregated warning instead.
         """
-        if self.spawn:
-            return self._serve_spawn(verbose)
         out: dict[int, object] = {}
         self.last_walls = [0.0] * self.n_workers
         for i, w in enumerate(self.workers):
@@ -222,41 +210,6 @@ class FleetRouter:
         self._warn_dead_buckets(out)
         return out
 
-    def _serve_spawn(self, verbose: bool) -> dict[int, object]:
-        import multiprocessing as mp
-        ctx = mp.get_context("spawn")
-        out: dict[int, object] = {}
-        self.last_walls = [0.0] * self.n_workers
-        procs = []
-        for i in range(self.n_workers):
-            mine = [j for j in self.jobs.values()
-                    if j.worker == i and not j.done]
-            if not mine:
-                continue
-            parent, child = ctx.Pipe(duplex=False)
-            spec = [(j.job_id, j.stream, j.n_frames, j.start) for j in mine]
-            p = ctx.Process(target=_spawn_serve,
-                            args=(self.cfg, self.server_cfg, self.n_classes,
-                                  self.seed + i, spec, child))
-            p.start()
-            procs.append((i, mine, p, parent))
-        for i, mine, p, parent in procs:
-            payload = parent.recv()
-            p.join()
-            if isinstance(payload, BaseException):
-                raise RuntimeError(
-                    f"spawned fleet worker {i} died") from payload
-            wall, results = payload
-            self.last_walls[i] = wall
-            for job_id, res in results:
-                self.jobs[job_id].done = True
-                self.jobs[job_id].result = res
-                out[job_id] = res
-                if verbose:
-                    print(f"[fleet] worker {i} job {job_id}:", res.summary())
-        self._warn_dead_buckets(out)
-        return out
-
     @property
     def aggregate_fps(self) -> float:
         """Fleet throughput of the last ``serve()``: total frames over the
@@ -267,7 +220,7 @@ class FleetRouter:
         return frames / wall if wall > 0 else 0.0
 
     def _warn_dead_buckets(self, results: dict) -> None:
-        if self.spawn or not results:
+        if not results:
             return
         dead_map = {}
         for i, w in enumerate(self.workers):
@@ -293,15 +246,9 @@ class FleetRouter:
 
     # -- migration / drain -------------------------------------------------
 
-    def _need_inprocess(self, what: str) -> None:
-        if self.spawn:
-            raise ValueError(f"{what} needs in-process workers "
-                             f"(spawn processes share no session state)")
-
     def migrate(self, job_id: int, to_worker: int) -> FleetJob:
         """Move one queued job between workers via the PR-9 migration
         surfaces; its remaining predictions are unchanged."""
-        self._need_inprocess("migrate")
         j = self.jobs[job_id]
         if j.done:
             raise ValueError(f"job {job_id} already served")
@@ -317,7 +264,6 @@ class FleetRouter:
         balanced: repeatedly move the hottest worker's smallest job to the
         coldest worker while that strictly shrinks the hot-cold gap.
         Returns the moved job ids (empty when already balanced)."""
-        self._need_inprocess("rebalance")
         moved: list[int] = []
         while not max_moves or len(moved) < max_moves:
             loads = [self.queued_seconds(i) for i in range(self.n_workers)]
@@ -340,7 +286,6 @@ class FleetRouter:
         a fresh replacement server on the shared prepared cache and
         restore into it. Jobs keep their ids and sids; the replacement
         takes the dead worker's slot. Returns the replacement."""
-        self._need_inprocess("drain")
         old = self.workers[worker]
         mine = [j for j in self.jobs.values()
                 if j.worker == worker and not j.done]
@@ -357,23 +302,6 @@ class FleetRouter:
         return repl
 
 
-def _spawn_serve(cfg, server_cfg, n_classes, seed, jobs, conn):
-    """Top-level spawn target: build a worker, serve its jobs, ship
-    ``(wall_s, [(job_id, StreamResult), ...])`` back over the pipe."""
-    try:
-        srv = StreamServer(cfg, server_cfg, n_classes=n_classes, seed=seed)
-        sessions = {job_id: srv.add_session(st, n_frames=nf, start=s0)
-                    for job_id, st, nf, s0 in jobs}
-        t0 = time.time()
-        results = srv.serve()
-        wall = time.time() - t0
-        conn.send((wall, [(job_id, results[s.sid])
-                          for job_id, s in sessions.items()]))
-    except BaseException as e:     # surface child failures to the router
-        conn.send(e)
-        raise
-
-
 def main(argv=None):
     from repro.configs.opto_vit import get_config
     from repro.data.pipeline import video_fleet
@@ -386,8 +314,6 @@ def main(argv=None):
                     help="base frames/stream; streams get a skewed "
                          "1x..3x mix so load-aware placement matters")
     ap.add_argument("--placement", choices=("cost", "rr"), default="cost")
-    ap.add_argument("--spawn", action="store_true",
-                    help="one spawn process per worker (own JAX runtime)")
     ap.add_argument("--img", type=int, default=96)
     ap.add_argument("--backend", default="bf16",
                     help="matmul backend (bf16 default: CPU-fast demo)")
@@ -402,7 +328,7 @@ def main(argv=None):
         ServingConfig(microbatch=4, chunk=8, force_bucket=0.5),
         warm_start=True, model_shards=args.model_shards)
     router = FleetRouter(cfg, sc, workers=args.workers,
-                         placement=args.placement, spawn=args.spawn)
+                         placement=args.placement)
     fleet = video_fleet(args.streams, img_size=args.img, patch=16,
                         cut_every=32)
     for i, st in enumerate(fleet):

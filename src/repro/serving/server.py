@@ -61,6 +61,8 @@ from dataclasses import dataclass, fields as _dc_fields
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint.checkpoint import latest_step, restore_flat
 from repro.checkpoint.checkpoint import save as _ckpt_save
@@ -97,6 +99,19 @@ def _gather_topk_rows(tokens, order, keep: int):
     re-sorting per bucket.
     """
     return jnp.take_along_axis(tokens, order[:, :keep, None], axis=1)
+
+
+def _whole_on_each_device(fn, mesh):
+    """``fn`` computed whole on every device of ``mesh``, its inputs and
+    outputs replicated, under ``shard_map`` — the form in which a TPU runs
+    the Pallas kernels of the (small) embed and RoI-gate programs on a
+    multi-chip mesh (XLA cannot partition them). Each device computes
+    exactly the one-device program, so results stay bitwise equal to it.
+    ``fn`` itself without a mesh."""
+    if mesh is None:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
 
 
 def interleave_rounds(groups, depth: int = 1) -> list:
@@ -250,34 +265,36 @@ class StreamServer:
 
         cfg_, pol = cfg, self.policy
         gpol = pol.gate_policy()
+        whole = functools.partial(_whole_on_each_device, mesh=self.mesh)
         if self.noise is None:
-            self._embed = jax.jit(
-                lambda p, f: embed_patches(p, f, cfg_, pol))
+            self._embed = jax.jit(whole(
+                lambda p, f: embed_patches(p, f, cfg_, pol)))
             self._encode = jax.jit(
                 lambda p, t: forward_vit_tokens(p, t, cfg_, pol)[0])
-            self._encode_dense = jax.jit(
-                lambda p, f, m: forward_vit_masked(p, f, m, cfg_, pol)[0])
+            self._encode_dense = jax.jit(whole(
+                lambda p, f, m: forward_vit_masked(p, f, m, cfg_, pol)[0]))
         else:
             # every noisy entry takes the DriftState as one extra traced
             # argument and installs the noise scope INSIDE the traced body
             # (`scoped`): the per-call-site key counter then restarts per
             # trace, so retraces, eager replays and cached executions all
             # assign identical keys for equal (params, inputs, DriftState)
-            self._embed = jax.jit(lambda p, f, ns: _noise_scoped(
-                ns, lambda: embed_patches(p, f, cfg_, pol)))
+            self._embed = jax.jit(whole(lambda p, f, ns: _noise_scoped(
+                ns, lambda: embed_patches(p, f, cfg_, pol))))
             self._encode = jax.jit(lambda p, t, ns: _noise_scoped(
                 ns, lambda: forward_vit_tokens(p, t, cfg_, pol)[0]))
-            self._encode_dense = jax.jit(lambda p, f, m, ns: _noise_scoped(
-                ns, lambda: forward_vit_masked(p, f, m, cfg_, pol)[0]))
+            self._encode_dense = jax.jit(whole(
+                lambda p, f, m, ns: _noise_scoped(
+                    ns, lambda: forward_vit_masked(p, f, m, cfg_, pol)[0])))
         if self.noise is not None and self.noise.noisy_gate:
-            self._score = jax.jit(lambda p, f, ns: _noise_scoped(
-                ns, lambda: mgnet_scores(p["mgnet"], f, self.mcfg, gpol)))
+            self._score = jax.jit(whole(lambda p, f, ns: _noise_scoped(
+                ns, lambda: mgnet_scores(p["mgnet"], f, self.mcfg, gpol))))
         else:
             # default: the RoI gate scores clean even under noise (see
             # ExecPolicy.gate_policy) — routing and bucket shapes stay
             # deterministic, so clean-vs-noisy runs compare frame-by-frame
-            self._score = jax.jit(
-                lambda p, f: mgnet_scores(p["mgnet"], f, self.mcfg, gpol))
+            self._score = jax.jit(whole(
+                lambda p, f: mgnet_scores(p["mgnet"], f, self.mcfg, gpol)))
         # one stable descending argsort per chunk (the ordering
         # select_topk_patches defines), then per-bucket static slices of it
         # — not a fresh full-chunk sort + gather per unique bucket
@@ -323,24 +340,24 @@ class StreamServer:
             self.warm_start()
 
     def _maybe_place(self, params):
-        """Pin the prepared weight cache onto a 2-D serving mesh — only
-        when the model-sharded encoder will actually engage. Params fed to
-        the *unsharded* jit must stay replicated: a committed model-axis
-        sharding there would make GSPMD add collectives to a graph whose
-        bitwise contract assumes none."""
-        if (self._ctx is None or "model" not in self.mesh.axis_names
-                or self.mesh.shape["model"] < 2):
+        """Pin the prepared weight cache onto the serving mesh, once: the
+        sharded encoder's layout when it will actually engage (model-axis
+        shardings on a 2-D mesh), a replica on every device otherwise
+        (left on device 0, the weights would be copied to the others on
+        every launch). Params fed to the *unsharded* jit must stay
+        replicated: a committed model-axis sharding there would make GSPMD
+        add collectives to a graph whose bitwise contract assumes none."""
+        if self._ctx is None:
             return params
         from repro.core.backend import place_params
         from repro.models import sharded_encoder, vit
-        if vit._fused_encoder_ineligible_reason(
-                params, self.cfg, self.policy) is not None:
-            return params
-        if sharded_encoder.sharded_encode_ineligible_reason(
-                params, self.cfg, self.policy, self._ctx) is not None:
-            return params
-        return place_params(params, vit.vit_logical_axes(self.cfg),
-                            self._ctx)
+        if (vit._fused_encoder_ineligible_reason(
+                params, self.cfg, self.policy) is None
+                and sharded_encoder.sharded_encode_ineligible_reason(
+                    params, self.cfg, self.policy, self._ctx) is None):
+            return place_params(params, vit.vit_logical_axes(self.cfg),
+                                self._ctx)
+        return jax.device_put(params, NamedSharding(self.mesh, P()))
 
     def _prepare(self, plan):
         """MR-tune the shared cache from the raw weights under ``plan``
@@ -1463,6 +1480,8 @@ def main(argv=None):
     ap.add_argument("--json", default="",
                     help="write per-session + aggregate results to this path")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.backend and args.backend not in available_backends():
         raise SystemExit(f"unknown backend {args.backend!r}; "

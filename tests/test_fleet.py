@@ -167,18 +167,3 @@ def test_dead_bucket_warning_aggregated():
     assert "worker 0" in str(dead[0].message)
     assert "worker 1" in str(dead[0].message)
 
-
-# -- spawn-mode guards ----------------------------------------------------
-
-
-def test_spawn_mode_guards_shared_state_surfaces():
-    """Spawn workers share no address space: migrate/rebalance/drain must
-    raise instead of silently corrupting, and pricing falls back to frame
-    counts (no in-process worker 0 to compile a cost model on)."""
-    r = FleetRouter(_cfg(), _sc(), workers=2, n_classes=8, spawn=True)
-    assert r.workers == []                   # built in the children
-    assert r.price_per_frame() == 1.0
-    for call in (lambda: r.migrate(0, 1), r.rebalance,
-                 lambda: r.drain(0)):
-        with pytest.raises(ValueError, match="in-process"):
-            call()

@@ -153,7 +153,8 @@ def test_fused_roi_attention_prequant_matches_float_composition():
     out = fused_roi_attention_prequant(
         x, qws[0].wq, qws[0].scale.reshape(-1),
         qws[1].wq, qws[1].scale.reshape(-1),
-        qws[2].wq, qws[2].scale.reshape(-1), mask, heads=heads)
+        qws[2].wq, qws[2].scale.reshape(-1), mask, heads=heads,
+        interpret=True)
 
     from repro.core.backend import ExecPolicy, linear
     pol = ExecPolicy(backend="photonic_pallas", quant_bits=8)

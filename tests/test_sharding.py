@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
 from repro.core import quant
@@ -19,9 +20,14 @@ from repro.distributed.sharding import (DATA_RULES, DEFAULT_RULES,
                                         validate_rules)
 
 
+def _mesh(shape, axes):
+    """A mesh of the kind launch/mesh.py builds: every axis Auto."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 @pytest.fixture(scope="module")
 def ctx():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = _mesh((1, 1), ("data", "model"))
     return ShardingCtx(mesh, DEFAULT_RULES)
 
 
@@ -33,7 +39,7 @@ def test_shard_noop_without_ctx():
 
 
 def test_ctx_installs_and_restores():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = _mesh((1, 1), ("data", "model"))
     assert current_ctx() is None
     with use_sharding(mesh):
         assert current_ctx() is not None
@@ -102,7 +108,7 @@ def test_named_sharding_roundtrip(ctx):
 def test_model_rules_mapping():
     """MODEL_RULES shards heads/d_ff over "model", keeps embed replicated
     (no FSDP at inference — see the table's comment)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = _mesh((1, 1), ("data", "model"))
     mctx = ShardingCtx(mesh, MODEL_RULES)
     assert mctx.spec("batch", "heads", None) == P("data", "model", None)
     assert mctx.spec("p_embed", "p_heads") == P(None, "model")
@@ -113,11 +119,11 @@ def test_model_rules_mapping():
 
 def test_rules_for_mesh_selection():
     assert rules_for_mesh(None) is None
-    assert rules_for_mesh(jax.make_mesh((1,), ("data",))) is DATA_RULES
+    assert rules_for_mesh(_mesh((1,), ("data",))) is DATA_RULES
     assert rules_for_mesh(
-        jax.make_mesh((1, 1), ("data", "model"))) is MODEL_RULES
+        _mesh((1, 1), ("data", "model"))) is MODEL_RULES
     assert rules_for_mesh(
-        jax.make_mesh((1, 1, 1), ("pod", "data", "model"))) is MULTIPOD_RULES
+        _mesh((1, 1, 1), ("pod", "data", "model"))) is MULTIPOD_RULES
 
 
 def test_validate_rules_raises_on_unmapped_axis():
@@ -153,7 +159,7 @@ def test_place_params_pins_quantized_weights():
     """place_params puts QuantizedWeight codes *and* scales under the
     logical-axis sharding; the scale's size-1 contraction dim falls back
     to replicated so per-out-channel scales follow their columns."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = _mesh((1, 1), ("data", "model"))
     mctx = ShardingCtx(mesh, MODEL_RULES)
     params = {
         "wq": QuantizedWeight(jnp.zeros((8, 16), jnp.int8),
@@ -176,15 +182,13 @@ def test_replicated_absmax_scale_bitwise_matches_unsharded():
     the result must equal core.quant.absmax_scale bit for bit — the op
     order (max -> pmax -> eps clamp -> reciprocal-multiply) is the whole
     contract."""
-    from jax.experimental.shard_map import shard_map
-
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = _mesh((1, 1), ("data", "model"))
     x = jax.random.normal(jax.random.PRNGKey(0), (16, 32), jnp.float32)
     ref = quant.absmax_scale(x, bits=8)
-    got = shard_map(
+    got = jax.shard_map(
         lambda t: replicated_absmax_scale(t, 8, ("data", "model")),
         mesh=mesh, in_specs=P(None, None), out_specs=P(),
-        check_rep=False)(x)
+        check_vma=False)(x)
     assert got.dtype == jnp.float32
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
@@ -195,11 +199,9 @@ def test_exact_int_psum_rejects_float():
 
 
 def test_exact_int_psum_identity_on_degenerate_axis():
-    from jax.experimental.shard_map import shard_map
-
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = _mesh((1, 1), ("data", "model"))
     x = jnp.arange(8, dtype=jnp.int32)
-    got = shard_map(lambda t: exact_int_psum(t, "model"), mesh=mesh,
-                    in_specs=P(None), out_specs=P(None),
-                    check_rep=False)(x)
+    got = jax.shard_map(lambda t: exact_int_psum(t, "model"), mesh=mesh,
+                        in_specs=P(None), out_specs=P(None),
+                        check_vma=False)(x)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(x))
