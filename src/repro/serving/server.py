@@ -87,6 +87,7 @@ from repro.serving.mask_cache import TemporalMaskCache
 from repro.serving.scheduler import MicroBatcher
 from repro.serving.session import (ServingConfig, StreamResult,
                                    StreamSession)
+from repro.serving.spans import Spans
 
 __all__ = ["ServerConfig", "StreamServer", "interleave_rounds", "main"]
 
@@ -266,60 +267,83 @@ class StreamServer:
         cfg_, pol = cfg, self.policy
         gpol = pol.gate_policy()
         whole = functools.partial(_whole_on_each_device, mesh=self.mesh)
+        # named, so that each program's XLA module reads jit_<name> in a
+        # profiler trace
         if self.noise is None:
-            self._embed = jax.jit(whole(
-                lambda p, f: embed_patches(p, f, cfg_, pol)))
-            self._encode = jax.jit(
-                lambda p, t: forward_vit_tokens(p, t, cfg_, pol)[0])
-            self._encode_dense = jax.jit(whole(
-                lambda p, f, m: forward_vit_masked(p, f, m, cfg_, pol)[0]))
+            def opto_embed(p, f):
+                return embed_patches(p, f, cfg_, pol)
+
+            def opto_encode(p, t):
+                return forward_vit_tokens(p, t, cfg_, pol)[0]
+
+            def opto_encode_dense(p, f, m):
+                return forward_vit_masked(p, f, m, cfg_, pol)[0]
         else:
             # every noisy entry takes the DriftState as one extra traced
             # argument and installs the noise scope INSIDE the traced body
             # (`scoped`): the per-call-site key counter then restarts per
             # trace, so retraces, eager replays and cached executions all
             # assign identical keys for equal (params, inputs, DriftState)
-            self._embed = jax.jit(whole(lambda p, f, ns: _noise_scoped(
-                ns, lambda: embed_patches(p, f, cfg_, pol))))
-            self._encode = jax.jit(lambda p, t, ns: _noise_scoped(
-                ns, lambda: forward_vit_tokens(p, t, cfg_, pol)[0]))
-            self._encode_dense = jax.jit(whole(
-                lambda p, f, m, ns: _noise_scoped(
-                    ns, lambda: forward_vit_masked(p, f, m, cfg_, pol)[0])))
+            def opto_embed(p, f, ns):
+                return _noise_scoped(
+                    ns, lambda: embed_patches(p, f, cfg_, pol))
+
+            def opto_encode(p, t, ns):
+                return _noise_scoped(
+                    ns, lambda: forward_vit_tokens(p, t, cfg_, pol)[0])
+
+            def opto_encode_dense(p, f, m, ns):
+                return _noise_scoped(
+                    ns, lambda: forward_vit_masked(p, f, m, cfg_, pol)[0])
+        self._embed = jax.jit(whole(opto_embed))
+        self._encode = jax.jit(opto_encode)
+        self._encode_dense = jax.jit(whole(opto_encode_dense))
         if self.noise is not None and self.noise.noisy_gate:
-            self._score = jax.jit(whole(lambda p, f, ns: _noise_scoped(
-                ns, lambda: mgnet_scores(p["mgnet"], f, self.mcfg, gpol))))
+            def mgnet_score(p, f, ns):
+                return _noise_scoped(ns, lambda: mgnet_scores(
+                    p["mgnet"], f, self.mcfg, gpol))
         else:
             # default: the RoI gate scores clean even under noise (see
             # ExecPolicy.gate_policy) — routing and bucket shapes stay
             # deterministic, so clean-vs-noisy runs compare frame-by-frame
-            self._score = jax.jit(whole(
-                lambda p, f: mgnet_scores(p["mgnet"], f, self.mcfg, gpol)))
+            def mgnet_score(p, f):
+                return mgnet_scores(p["mgnet"], f, self.mcfg, gpol)
+        self._score = jax.jit(whole(mgnet_score))
+
         # one stable descending argsort per chunk (the ordering
         # select_topk_patches defines), then per-bucket static slices of it
         # — not a fresh full-chunk sort + gather per unique bucket
-        self._order = jax.jit(
-            lambda s: jnp.argsort(s, axis=-1, stable=True, descending=True))
-        self._gather = {
-            k: jax.jit(functools.partial(_gather_topk_rows, keep=k))
-            for k in self.ladder.sizes}
+        def patch_order(s):
+            return jnp.argsort(s, axis=-1, stable=True, descending=True)
+
+        def _gather_k(k: int):
+            def gather_topk(tokens, order):
+                return _gather_topk_rows(tokens, order, k)
+            return jax.jit(gather_topk)
+
+        self._order = jax.jit(patch_order)
+        self._gather = {k: _gather_k(k) for k in self.ladder.sizes}
         self._encode_one = {}
         if self.serve_cfg.one_shape:
-            if self.noise is None:
-                def _one(k: int):
-                    return jax.jit(lambda p, t: forward_vit_tokens(
-                        p, t, cfg_, pol, kv_len=k)[0])
-            else:
-                def _one(k: int):
-                    return jax.jit(lambda p, t, ns: _noise_scoped(
-                        ns, lambda: forward_vit_tokens(
-                            p, t, cfg_, pol, kv_len=k)[0]))
+            def _one(k: int):
+                if self.noise is None:
+                    def opto_encode_k(p, t):
+                        return forward_vit_tokens(p, t, cfg_, pol,
+                                                  kv_len=k)[0]
+                else:
+                    def opto_encode_k(p, t, ns):
+                        return _noise_scoped(ns, lambda: forward_vit_tokens(
+                            p, t, cfg_, pol, kv_len=k)[0])
+                return jax.jit(opto_encode_k)
             self._encode_one = {k: _one(int(k)) for k in self.ladder.sizes}
 
         self._sessions: list[StreamSession] = []
         self._next_sid = 0
         self.batcher: MicroBatcher | None = None
         self.flush_log: list[tuple] = []   # (owner sids, bucket k, n_real)
+        # counters always on, spans off until ``spans.enable()``
+        # (serving/spans.py)
+        self.spans = Spans()
         self.warm_s = 0.0
         # fault tolerance: the injector exists only under a FaultSpec (the
         # fault-free loop must stay on the pre-fault-layer instruction
@@ -709,62 +733,71 @@ class StreamServer:
         ``ServeError`` attributing the failing bucket/sessions/round and
         carrying partial results for sessions that had fully drained."""
         sc = self.serve_cfg
-        if self._inflight is None:
-            live = [s for s in self._sessions if not s.finished]
-            if not live:
-                return {}
-            for s in live:
-                s.open()
-            self.batcher = MicroBatcher(sc.microbatch)
-            self.flush_log = []
-            rnd, offset = self._resume if self._resume else (0, 0)
-            self._resume = None
-            st = {"live": live, "rnd": rnd, "offset": offset,
-                  "wall_s": 0.0, "retuned_at": 0,
-                  "early": self._restore_pending(live)}
-            self._inflight = st
-        else:
-            st = self._inflight
-        live = st["live"]
-        by_sid = {s.sid: s for s in live}
-        t0 = time.time()
-        try:
-            done = self._serve_loop(st, by_sid, t0, verbose, max_rounds)
-        except BaseException as e:
-            # an unexpected mid-serve failure poisons the half-served
-            # sessions: their accounting/mask-cache state is partial, and
-            # re-opening them on the next serve() would re-ingest from
-            # frame 0 and double-count — they are abandoned. Sessions that
-            # had already fully drained lose nothing: their finished
-            # results ride out on the ServeError.
+        spans = self.spans
+        with spans.span("serve.call"):
+            if self._inflight is None:
+                live = [s for s in self._sessions if not s.finished]
+                if not live:
+                    return {}
+                for s in live:
+                    s.open()
+                self.batcher = MicroBatcher(sc.microbatch)
+                self.flush_log = []
+                rnd, offset = self._resume if self._resume else (0, 0)
+                self._resume = None
+                st = {"live": live, "rnd": rnd, "offset": offset,
+                      "wall_s": 0.0, "retuned_at": 0,
+                      "early": self._restore_pending(live)}
+                self._inflight = st
+            else:
+                st = self._inflight
+            live = st["live"]
+            by_sid = {s.sid: s for s in live}
+            t0 = time.time()
+            try:
+                done = self._serve_loop(st, by_sid, t0, verbose, max_rounds)
+            except BaseException as e:
+                # an unexpected mid-serve failure poisons the half-served
+                # sessions: their accounting/mask-cache state is partial,
+                # and re-opening them on the next serve() would re-ingest
+                # from frame 0 and double-count — they are abandoned.
+                # Sessions that had already fully drained lose nothing:
+                # their finished results ride out on the ServeError.
+                st["wall_s"] += time.time() - t0
+                wall = st["wall_s"]
+                partial = {s.sid: s.finish(wall) for s in live
+                           if s.drained and (
+                               s.failed_reason
+                               or s.acct.frames == s.frames_seen)}
+                for s in live:
+                    s.finished = True
+                self._inflight = None
+                self._sessions = [s for s in self._sessions
+                                  if not s.finished]
+                if isinstance(e, ServeError):
+                    e.partial_results.update(partial)
+                    raise
+                ctx = {"round": st["rnd"],
+                       "sessions": [s.sid for s in live if not s.drained]}
+                raise ServeError(
+                    f"serve() died at round {ctx['round']} (sessions "
+                    f"{ctx['sessions']} mid-stream): {e}", context=ctx,
+                    partial_results=partial) from e
             st["wall_s"] += time.time() - t0
+            if not done:
+                return {}           # paused by max_rounds; serve() resumes
             wall = st["wall_s"]
-            partial = {s.sid: s.finish(wall) for s in live
-                       if s.drained and (s.failed_reason
-                                         or s.acct.frames == s.frames_seen)}
+            results = {}
             for s in live:
-                s.finished = True
+                with spans.span("serve.finish", s.sid):
+                    spans.host_syncs += len(s.deferred)
+                    results[s.sid] = s.finish(wall)
+                spans.end("serve.session", s.sid)
             self._inflight = None
+            # finished sessions leave the registry (long-lived servers and
+            # the engine shim's run-per-session pattern stay bounded)
             self._sessions = [s for s in self._sessions if not s.finished]
-            if isinstance(e, ServeError):
-                e.partial_results.update(partial)
-                raise
-            ctx = {"round": st["rnd"],
-                   "sessions": [s.sid for s in live if not s.drained]}
-            raise ServeError(
-                f"serve() died at round {ctx['round']} (sessions "
-                f"{ctx['sessions']} mid-stream): {e}", context=ctx,
-                partial_results=partial) from e
-        st["wall_s"] += time.time() - t0
-        if not done:
-            return {}           # paused by max_rounds; serve() resumes
-        wall = st["wall_s"]
-        results = {s.sid: s.finish(wall) for s in live}
-        self._inflight = None
-        # finished sessions leave the registry (long-lived servers and
-        # the engine shim's run-per-session pattern stay bounded)
-        self._sessions = [s for s in self._sessions if not s.finished]
-        return results
+            return results
 
     def _serve_loop(self, st, by_sid, t0, verbose, max_rounds) -> bool:
         """Run scheduling rounds until every live session drains (returns
@@ -773,6 +806,7 @@ class StreamServer:
         and checkpoints."""
         sc = self.serve_cfg
         ctl = self.controller
+        spans = self.spans
         live = st["live"]
         rounds = 0
         with use_sharding(self.mesh, self._rules):
@@ -788,104 +822,115 @@ class StreamServer:
             while any(not s.drained for s in live):
                 if max_rounds and rounds >= max_rounds:
                     return False
-                rnd = st["rnd"]
-                # the controller owns the re-timing knobs when present;
-                # kn is re-read every round so a step() lands immediately
-                kn = ctl.knobs if ctl is not None else None
-                max_wait = (kn.max_wait_chunks if kn is not None
-                            else sc.max_wait_chunks)
-                depth = (kn.interleave_depth if kn is not None
-                         else sc.interleave_depth)
-                offset = st["offset"]
-                rot = live[offset:] + live[:offset]
-                st["offset"] = (offset + 1) % len(live)
-                per = {s.sid: [] for s in rot}
-                late: list = []
-                for s in rot:
-                    if s.ingest_done:
-                        continue
-                    if (sc.max_pending_rows > 0 and self.batcher.pending
-                            >= sc.max_pending_rows):
-                        # load shedding: the queue bound is hit, so this
-                        # chunk is pulled off the sensor and dropped whole
-                        # (deferring it would deadlock: under max_wait=0 a
-                        # partial queue only fills from its own session's
-                        # future ingest)
-                        batch = s.next_batch()
-                        if batch is not None:
-                            s.shed(int((np.asarray(batch["frame_idx"])
-                                        < s.limit).sum()))
-                        continue
-                    if self._injector is not None:
-                        # fault check BEFORE next_batch: a raised fault
-                        # must never half-consume the prefetch iterator
-                        try:
-                            self._injector.ingest(s.sid, s.chunks_done,
-                                                  attempt=s.ingest_attempts)
-                        except TransientFault:
-                            s.ingest_attempts += 1
-                            s.retries += 1
-                            continue          # same chunk retries next round
-                        except FatalFault as e:
-                            self._fail_sessions((s.sid,), str(e), by_sid)
-                            continue
-                        s.ingest_attempts = 0
-                    batch = s.next_batch()
-                    if batch is not None:
-                        per[s.sid].extend(self._ingest_chunk(s, batch, rnd))
-                if sc.mix_streams:
-                    if all(s.ingest_done for s in live):
-                        late.extend(self.batcher.drain())
-                        for s in live:
-                            s.drained = True
-                else:
+                with spans.span("serve.round"):
+                    rnd = st["rnd"]
+                    # the controller owns the re-timing knobs when
+                    # present; kn is re-read every round so a step()
+                    # lands immediately
+                    kn = ctl.knobs if ctl is not None else None
+                    max_wait = (kn.max_wait_chunks if kn is not None
+                                else sc.max_wait_chunks)
+                    depth = (kn.interleave_depth if kn is not None
+                             else sc.interleave_depth)
+                    offset = st["offset"]
+                    rot = live[offset:] + live[:offset]
+                    st["offset"] = (offset + 1) % len(live)
+                    per = {s.sid: [] for s in rot}
+                    late: list = []
                     for s in rot:
-                        if s.ingest_done and not s.drained:
-                            per[s.sid].extend(self.batcher.drain(
-                                select=lambda key, sid=s.sid:
-                                key[1] == sid))
-                            s.drained = True
-                if max_wait > 0:
-                    late.extend(self.batcher.flush_stale(rnd - max_wait))
-                if kn is not None and kn.flush_threshold:
-                    late.extend(self.batcher.flush_filled(
-                        lambda key: kn.flush_threshold.get(
-                            key[0] if isinstance(key, tuple) else key,
-                            self.batcher.microbatch)))
-                self._round = rnd
-                for fb in interleave_rounds([per[s.sid] for s in rot],
-                                            depth):
-                    self._safe_finish(fb, by_sid)
-                for fb in late:
-                    self._safe_finish(fb, by_sid)
-                st["rnd"] = rnd + 1
-                rounds += 1
-                if self._injector is not None:
-                    self._injector.round_tick(rnd)   # may raise ServerCrash
-                if (sc.checkpoint_every > 0 and sc.checkpoint_dir
-                        and st["rnd"] % sc.checkpoint_every == 0):
-                    try:
-                        self.checkpoint()
-                    except CheckpointFault as e:
-                        # checkpoint I/O loss degrades gracefully: serving
-                        # continues on the last good snapshot
-                        self.checkpoint_failures += 1
-                        warnings.warn(f"checkpoint skipped: {e}",
-                                      stacklevel=2)
-                if ctl is not None:
-                    done = sum(s.acct.frames for s in live)
-                    if done - st["retuned_at"] >= sc.retune_every:
-                        ctl.step(self.batcher.queue_stats(), done,
-                                 time.time() - t0)
-                        st["retuned_at"] = done
-                if verbose and st["rnd"] % sc.report_every == 0:
-                    dt = time.time() - t0
-                    done = sum(s.acct.frames for s in live)
-                    print(f"[server] round {st['rnd']:>4d}  {done:>5d} "
-                          f"frames  {done / dt:7.1f} frames/s aggregate  "
-                          f"(pending {self.batcher.pending}, "
-                          f"{sum(not s.ingest_done for s in live)} "
-                          f"streams ingesting)")
+                        if s.ingest_done:
+                            continue
+                        if (sc.max_pending_rows > 0
+                                and self.batcher.pending
+                                >= sc.max_pending_rows):
+                            # load shedding: the queue bound is hit, so
+                            # this chunk is pulled off the sensor and
+                            # dropped whole (deferring it would deadlock:
+                            # under max_wait=0 a partial queue only fills
+                            # from its own session's future ingest)
+                            batch = s.next_batch()
+                            if batch is not None:
+                                s.shed(int((np.asarray(batch["frame_idx"])
+                                            < s.limit).sum()))
+                            continue
+                        if self._injector is not None:
+                            # fault check BEFORE next_batch: a raised fault
+                            # must never half-consume the prefetch iterator
+                            try:
+                                self._injector.ingest(
+                                    s.sid, s.chunks_done,
+                                    attempt=s.ingest_attempts)
+                            except TransientFault:
+                                s.ingest_attempts += 1
+                                s.retries += 1
+                                continue    # same chunk retries next round
+                            except FatalFault as e:
+                                self._fail_sessions((s.sid,), str(e),
+                                                    by_sid)
+                                continue
+                            s.ingest_attempts = 0
+                        spans.begin("serve.session", s.sid)
+                        with spans.span("serve.ingest", s.sid):
+                            batch = s.next_batch()
+                        if batch is not None:
+                            per[s.sid].extend(
+                                self._ingest_chunk(s, batch, rnd))
+                    if sc.mix_streams:
+                        if all(s.ingest_done for s in live):
+                            late.extend(self.batcher.drain())
+                            for s in live:
+                                s.drained = True
+                    else:
+                        for s in rot:
+                            if s.ingest_done and not s.drained:
+                                per[s.sid].extend(self.batcher.drain(
+                                    select=lambda key, sid=s.sid:
+                                    key[1] == sid))
+                                s.drained = True
+                    if max_wait > 0:
+                        late.extend(
+                            self.batcher.flush_stale(rnd - max_wait))
+                    if kn is not None and kn.flush_threshold:
+                        late.extend(self.batcher.flush_filled(
+                            lambda key: kn.flush_threshold.get(
+                                key[0] if isinstance(key, tuple) else key,
+                                self.batcher.microbatch)))
+                    self._round = rnd
+                    for fb in interleave_rounds([per[s.sid] for s in rot],
+                                                depth):
+                        self._safe_finish(fb, by_sid)
+                    for fb in late:
+                        self._safe_finish(fb, by_sid)
+                    st["rnd"] = rnd + 1
+                    rounds += 1
+                    if self._injector is not None:
+                        # may raise ServerCrash
+                        self._injector.round_tick(rnd)
+                    if (sc.checkpoint_every > 0 and sc.checkpoint_dir
+                            and st["rnd"] % sc.checkpoint_every == 0):
+                        try:
+                            self.checkpoint()
+                        except CheckpointFault as e:
+                            # checkpoint I/O loss degrades gracefully:
+                            # serving continues on the last good snapshot
+                            self.checkpoint_failures += 1
+                            warnings.warn(f"checkpoint skipped: {e}",
+                                          stacklevel=2)
+                    if ctl is not None:
+                        done = sum(s.acct.frames for s in live)
+                        if done - st["retuned_at"] >= sc.retune_every:
+                            ctl.step(self.batcher.queue_stats(), done,
+                                     time.time() - t0)
+                            st["retuned_at"] = done
+                    if verbose and st["rnd"] % sc.report_every == 0:
+                        dt = time.time() - t0
+                        done = sum(s.acct.frames for s in live)
+                        print(f"[server] round {st['rnd']:>4d}  "
+                              f"{done:>5d} frames  {done / dt:7.1f} "
+                              f"frames/s aggregate  "
+                              f"(pending {self.batcher.pending}, "
+                              f"{sum(not s.ingest_done for s in live)} "
+                              f"streams ingesting)")
         if verbose:
             for s in live:
                 print(f"[server] session {s.sid}:", s.acct.summary())
@@ -896,40 +941,52 @@ class StreamServer:
         shared jit, route on the shared ladder, and push per-bucket groups
         into the shared batcher. Returns flushes that became ready."""
         sc = self.serve_cfg
+        spans = self.spans
         frames = batch["frames"]                           # device view
         idxs = batch["frame_idx"]
         valid = idxs < s.limit
-        scores_np, n_scored = s.cache.gate(batch["frames_host"], idxs,
-                                           self._score_fn, eligible=valid)
+        spans.chunks_ingested += 1
+        spans.h2d_bytes += batch["frames_host"].nbytes
+        with spans.span("serve.gate", s.sid):
+            scores_np, n_scored = s.cache.gate(
+                batch["frames_host"], idxs, self._score_fn, eligible=valid)
+        if n_scored:
+            # one score launch, its scores pulled to the host
+            spans.score_launches += 1
+            spans.host_syncs += 1
         s.acct.add_mgnet(n_scored)
-        toks = self._embed(self.params, frames,
-                           *self._nargs())                 # (C, N, d)
-        # budget decision on host: scores are already host-resident from
-        # the mask cache, and mask_budget stays in numpy for them
-        if sc.force_bucket > 0:
-            pin = self.ladder.route(
-                int(round(sc.force_bucket * self.n_patches)))
-            routes = np.full(frames.shape[0], pin)
-        else:
-            routes = self.ladder.route_many(
-                mask_budget(scores_np, self.mcfg.t_reg))
+        with spans.span("serve.route", s.sid):
+            toks = self._embed(self.params, frames,
+                               *self._nargs())             # (C, N, d)
+            # budget decision on host: scores are already host-resident
+            # from the mask cache, and mask_budget stays in numpy for them
+            if sc.force_bucket > 0:
+                pin = self.ladder.route(
+                    int(round(sc.force_bucket * self.n_patches)))
+                routes = np.full(frames.shape[0], pin)
+            else:
+                routes = self.ladder.route_many(
+                    mask_budget(scores_np, self.mcfg.t_reg))
 
-        order = self._order(jnp.asarray(scores_np))        # (C, N), shared
-        permuted = (self._gather[self.ladder.cap](toks, order)
-                    if sc.one_shape else None)             # (C, cap, d)
-        out = []
-        for k in np.unique(routes[valid]):
-            k = int(k)
-            sel = np.flatnonzero((routes == k) & valid)
-            # one-shape mode ships the shared cap-size permutation and
-            # prunes via the static per-bucket kv_len at encode time
-            pruned = (permuted if sc.one_shape
-                      else self._gather[k](toks, order))   # (C, k, d)
-            s.record_route(k, len(sel))
-            group = pruned if len(sel) == frames.shape[0] else pruned[sel]
-            key = k if sc.mix_streams else (k, s.sid)
-            out.extend(self.batcher.push_many(
-                key, group, [(s.sid, int(idxs[i])) for i in sel], now=rnd))
+            spans.h2d_bytes += scores_np.nbytes
+            order = self._order(jnp.asarray(scores_np))    # (C, N), shared
+            permuted = (self._gather[self.ladder.cap](toks, order)
+                        if sc.one_shape else None)         # (C, cap, d)
+            out = []
+            for k in np.unique(routes[valid]):
+                k = int(k)
+                sel = np.flatnonzero((routes == k) & valid)
+                # one-shape mode ships the shared cap-size permutation and
+                # prunes via the static per-bucket kv_len at encode time
+                pruned = (permuted if sc.one_shape
+                          else self._gather[k](toks, order))  # (C, k, d)
+                s.record_route(k, len(sel))
+                group = (pruned if len(sel) == frames.shape[0]
+                         else pruned[sel])
+                key = k if sc.mix_streams else (k, s.sid)
+                out.extend(self.batcher.push_many(
+                    key, group, [(s.sid, int(idxs[i])) for i in sel],
+                    now=rnd))
         s.frames_seen += int(valid.sum())
         return out
 
@@ -998,80 +1055,89 @@ class StreamServer:
         inj = self._injector
         tag = fb.frame_idx[0] if fb.frame_idx else (0, 0)
         timed = self.controller is not None or self._watchdog
-        attempt = 0
-        while True:
-            try:
-                if inj is not None:
-                    inj.flush(k, tag, attempt=attempt)
-                t0 = time.perf_counter() if timed else 0.0
-                tokens = self._place(fb.tokens)
-                aot = self._encode_aot.get(k)
-                if aot is not None:
-                    logits = aot(self.params, tokens, *self._nargs())
-                elif sc.one_shape:
-                    logits = self._encode_one[k](self.params, tokens,
-                                                 *self._nargs())
-                else:
-                    logits = self._encode(self.params, tokens,
-                                          *self._nargs())
-                # encodes are billed at bucket k: the packed prefix is
-                # contiguous, so the accelerator's static schedule streams
-                # only the k live rows through every core. Padded rows
-                # ([n_real:]) are never predicted or accounted.
-                preds = jnp.argmax(logits[:fb.n_real], -1)
-                if inj is not None:
-                    stall = inj.stall_s(k, tag)
-                    if stall > 0:
-                        # injected straggler: the flush completes but slow
-                        # — the watchdog's detection target
-                        preds.block_until_ready()
-                        time.sleep(stall)
-                break
-            except TransientFault as e:
-                attempt += 1
-                for sid in {s for s, _ in fb.frame_idx}:
-                    if sid in by_sid:
-                        by_sid[sid].retries += 1
-                if attempt > sc.retry_limit:
-                    raise SessionFailure(
-                        sorted({s for s, _ in fb.frame_idx}),
-                        f"retry limit ({sc.retry_limit}) exhausted: {e}",
-                    ) from e
-                time.sleep(min(sc.retry_backoff_s * 2 ** (attempt - 1),
-                               1.0))
-            except FatalFault as e:
-                raise SessionFailure(sorted({s for s, _ in fb.frame_idx}),
-                                     str(e)) from e
         owners: dict[int, tuple[list, list]] = {}
         for row, (sid, fidx) in enumerate(fb.frame_idx):
             rows, fidxs = owners.setdefault(sid, ([], []))
             rows.append(row)
             fidxs.append(fidx)
-        if timed:
-            # observed flush latency: launch to materialized result. The
-            # sync costs the autotuned path its async overlap — accepted,
-            # it is what makes the telemetry the controller calibrates
-            # against an honest per-flush number.
-            preds.block_until_ready()
-            wall = time.perf_counter() - t0
-            if self.controller is not None:
-                self.controller.record_flush(k, fb.n_real, len(owners),
-                                             wall, rnd)
-            elif self.telemetry is not None:
-                # watchdog-only path: feed the straggler detector directly
-                self.telemetry.record(k, fb.n_real, sc.microbatch,
-                                      len(owners), wall, rnd)
-        for sid, (rows, fidxs) in owners.items():
-            sess = by_sid[sid]
-            sess.record_flush(k, len(rows))
+        sids = tuple(sorted(owners))
+        spans = self.spans
+        # the span's clock times a timed flush: launch to materialized
+        # result, failed attempts and their backoff included
+        with spans.span("serve.flush", spans.flushes, bucket=k, owners=sids,
+                        clock=timed) as sp:
+            attempt = 0
+            while True:
+                try:
+                    if inj is not None:
+                        inj.flush(k, tag, attempt=attempt)
+                    tokens = self._place(fb.tokens)
+                    aot = self._encode_aot.get(k)
+                    if aot is not None:
+                        logits = aot(self.params, tokens, *self._nargs())
+                    elif sc.one_shape:
+                        logits = self._encode_one[k](self.params, tokens,
+                                                     *self._nargs())
+                    else:
+                        logits = self._encode(self.params, tokens,
+                                              *self._nargs())
+                    # encodes are billed at bucket k: the packed prefix is
+                    # contiguous, so the accelerator's static schedule streams
+                    # only the k live rows through every core. Padded rows
+                    # ([n_real:]) are never predicted or accounted.
+                    preds = jnp.argmax(logits[:fb.n_real], -1)
+                    if inj is not None:
+                        stall = inj.stall_s(k, tag)
+                        if stall > 0:
+                            # injected straggler: the flush completes but slow
+                            # — the watchdog's detection target
+                            preds.block_until_ready()
+                            time.sleep(stall)
+                    break
+                except TransientFault as e:
+                    attempt += 1
+                    for sid in {s for s, _ in fb.frame_idx}:
+                        if sid in by_sid:
+                            by_sid[sid].retries += 1
+                    if attempt > sc.retry_limit:
+                        raise SessionFailure(
+                            sorted({s for s, _ in fb.frame_idx}),
+                            f"retry limit ({sc.retry_limit}) exhausted: {e}",
+                        ) from e
+                    time.sleep(min(sc.retry_backoff_s * 2 ** (attempt - 1),
+                                   1.0))
+                except FatalFault as e:
+                    raise SessionFailure(sorted({s for s, _ in fb.frame_idx}),
+                                         str(e)) from e
             if timed:
-                sess.acct.add_flush_wall(k, wall)
-            sess.add_deferred(fidxs, preds if len(owners) == 1
-                              else preds[np.asarray(rows)])
-        self.flush_log.append((tuple(sorted(owners)), k, fb.n_real))
-        # the device ages by the frames this flush pushed through it; the
-        # flush itself observed the pre-advance state
-        self._advance_drift(fb.n_real)
+                # observed flush latency: launch to materialized result. The
+                # sync costs the autotuned path its async overlap — accepted,
+                # it is what makes the telemetry the controller calibrates
+                # against an honest per-flush number.
+                preds.block_until_ready()
+                spans.host_syncs += 1
+                wall = sp.elapsed_s()
+                if self.controller is not None:
+                    self.controller.record_flush(k, fb.n_real, len(owners),
+                                                 wall, rnd)
+                elif self.telemetry is not None:
+                    # watchdog-only path: feed the straggler detector directly
+                    self.telemetry.record(k, fb.n_real, sc.microbatch,
+                                          len(owners), wall, rnd)
+            for sid, (rows, fidxs) in owners.items():
+                sess = by_sid[sid]
+                sess.record_flush(k, len(rows))
+                if timed:
+                    sess.acct.add_flush_wall(k, wall)
+                sess.add_deferred(fidxs, preds if len(owners) == 1
+                                  else preds[np.asarray(rows)])
+            self.flush_log.append((sids, k, fb.n_real))
+            spans.flushes += 1
+            spans.rows_launched += fb.tokens.shape[0]
+            spans.rows_real += fb.n_real
+            # the device ages by the frames this flush pushed through it; the
+            # flush itself observed the pre-advance state
+            self._advance_drift(fb.n_real)
 
     # -- checkpoint / restore / migration ----------------------------------
 
