@@ -197,9 +197,10 @@ def mask_budget(scores, t_reg: float = 0.5):
 
     This is the *token budget* a frame requests from the serving bucket
     ladder: the number of patches whose sigmoid score clears ``t_reg``.
-    Accepts numpy or jax scores and stays in that domain — the serving
-    engine's routing decision runs on host-resident cached scores, and a
-    device round-trip per chunk would cost more than the count itself.
+    Accepts numpy or jax scores and stays in that domain — score-driven
+    serving routes on the host, on scores pulled once per scored chunk
+    (``TemporalMaskCache.host_scores``), and counts in numpy so routes
+    are bitwise the host rule's.
     """
     if isinstance(scores, np.ndarray):
         keep = 1.0 / (1.0 + np.exp(-scores.astype(np.float64))) > t_reg

@@ -83,7 +83,7 @@ from repro.serving.buckets import BucketLadder
 from repro.serving.faults import (CheckpointFault, FatalFault, FaultInjector,
                                   FaultSpec, ServeError, ServerCrash,
                                   SessionFailure, TransientFault)
-from repro.serving.mask_cache import TemporalMaskCache
+from repro.serving.mask_cache import GateOps, TemporalMaskCache
 from repro.serving.scheduler import MicroBatcher
 from repro.serving.session import (ServingConfig, StreamResult,
                                    StreamSession)
@@ -309,6 +309,9 @@ class StreamServer:
             def mgnet_score(p, f):
                 return mgnet_scores(p["mgnet"], f, self.mcfg, gpol)
         self._score = jax.jit(whole(mgnet_score))
+        # MGNet's padded input gathered from the chunk already on the
+        # device, and the per-frame score selection (serving/mask_cache.py)
+        self._gate_ops = GateOps.build(whole)
 
         # one stable descending argsort per chunk (the ordering
         # select_topk_patches defines), then per-bucket static slices of it
@@ -491,9 +494,14 @@ class StreamServer:
             zf = jnp.zeros((sc.chunk, cfg.img_size, cfg.img_size, 3),
                            jnp.float32)
             toks = self._embed(self.params, zf, *self._nargs())  # (C, N, d)
-            self._score_fn(zf).block_until_ready()
-            zs = jnp.asarray(np.zeros((sc.chunk, self.n_patches),
-                                      np.float32))
+            # the gate's programs as a session meets them: a cold cache
+            # (no reference), a warm one scoring, and one scoring nothing
+            ix = np.arange(sc.chunk, dtype=np.int32)
+            gops = self._gate_ops
+            fresh = self._score_fn(gops.inputs(zf, ix))
+            zs, ref = gops.scores(None, fresh, ix)
+            zs, ref = gops.scores(ref, fresh, ix)
+            gops.scores(ref, None, 0 * ix)[0].block_until_ready()
             order = self._order(zs)                        # (C, N) i32
             warm_gathers = ((self.ladder.cap,) if sc.one_shape
                             else targets)
@@ -947,29 +955,35 @@ class StreamServer:
         valid = idxs < s.limit
         spans.chunks_ingested += 1
         spans.h2d_bytes += batch["frames_host"].nbytes
+        pinned = sc.force_bucket > 0
         with spans.span("serve.gate", s.sid):
-            scores_np, n_scored = s.cache.gate(
-                batch["frames_host"], idxs, self._score_fn, eligible=valid)
+            # the walk on the host view, MGNet on the device view: the
+            # scores stay on the device
+            scores, n_scored = s.cache.gate(
+                batch["frames_host"], idxs, self._score_fn, eligible=valid,
+                device_frames=frames, ops=self._gate_ops)
+            if not pinned:
+                # score-driven routing reads the budgets on the host; a
+                # pin reads nothing, so the gate never waits on the device
+                scores_np, pulled = s.cache.host_scores(scores, n_scored)
+                spans.gate_pulls += pulled
+                spans.host_syncs += pulled
         if n_scored:
-            # one score launch, its scores pulled to the host
             spans.score_launches += 1
-            spans.host_syncs += 1
         s.acct.add_mgnet(n_scored)
         with spans.span("serve.route", s.sid):
             toks = self._embed(self.params, frames,
                                *self._nargs())             # (C, N, d)
-            # budget decision on host: scores are already host-resident
-            # from the mask cache, and mask_budget stays in numpy for them
-            if sc.force_bucket > 0:
+            if pinned:
                 pin = self.ladder.route(
                     int(round(sc.force_bucket * self.n_patches)))
                 routes = np.full(frames.shape[0], pin)
             else:
+                # mask_budget stays in numpy on the pulled scores, so
+                # routes are bitwise the host rule's
                 routes = self.ladder.route_many(
                     mask_budget(scores_np, self.mcfg.t_reg))
-
-            spans.h2d_bytes += scores_np.nbytes
-            order = self._order(jnp.asarray(scores_np))    # (C, N), shared
+            order = self._order(scores)                    # (C, N), shared
             permuted = (self._gather[self.ladder.cap](toks, order)
                         if sc.one_shape else None)         # (C, cap, d)
             out = []
@@ -1398,11 +1412,11 @@ class StreamServer:
                 break
             frames, idxs = batch["frames"], batch["frame_idx"]
             valid = idxs < s.limit
-            scores_np, n_scored = s.cache.gate(batch["frames_host"], idxs,
-                                               self._score_fn,
-                                               eligible=valid)
+            scores, n_scored = s.cache.gate(
+                batch["frames_host"], idxs, self._score_fn, eligible=valid,
+                device_frames=frames, ops=self._gate_ops)
             s.acct.add_mgnet(n_scored)
-            mask = (jax.nn.sigmoid(jnp.asarray(scores_np))
+            mask = (jax.nn.sigmoid(scores)
                     > self.mcfg.t_reg).astype(jnp.float32)
             logits = self._encode_dense(self.params, frames, mask,
                                         *self._nargs())

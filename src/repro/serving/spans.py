@@ -3,11 +3,15 @@
 ``StreamServer.spans`` is one ``Spans``. It holds two things:
 
 * **counters**, plain integer attributes that the loop bumps on every
-  call and that are always on: ``chunks_ingested``, ``h2d_bytes``,
-  ``score_launches``, ``host_syncs`` (each point where the loop waits on
-  the device: the MGNet score pull in the gate, each deferred-prediction
-  pull when a session finishes, a timed flush's ``block_until_ready``),
-  ``flushes``, ``rows_launched`` and ``rows_real``;
+  call and that are always on: ``chunks_ingested``, ``h2d_bytes`` (the
+  chunks' frames put to the device; the gate's few int32 index vectors
+  are not counted), ``score_launches``, ``gate_pulls`` (the chunks whose
+  region scores crossed to the host: one per scored chunk under
+  score-driven routing, none under a ``force_bucket`` pin),
+  ``host_syncs`` (each point where the loop waits on the device: a gate
+  pull, each deferred-prediction pull when a session finishes, a timed
+  flush's ``block_until_ready``), ``flushes``, ``rows_launched`` and
+  ``rows_real``;
 * **spans**, off until ``enable()``. Off, ``span()`` is one attribute
   check that returns a shared no-op context. On, each span opens a
   ``jax.profiler.TraceAnnotation`` of its name (so a profiler session
@@ -17,13 +21,14 @@
 The serving loop's spans (``serving/server.py``): ``serve.call`` (one
 ``serve()``), ``serve.round`` (one scheduling round), and inside a round
 ``serve.ingest`` (the chunk's pull and its prefetched host-to-device
-put), ``serve.gate`` (the mask cache's walk, MGNet's launch and its score
-pull), ``serve.route`` (embed, budget, order and gather launches, the
-batcher push) and ``serve.flush`` (place, encode launch, argmax,
-bookkeeping); ``serve.finish`` (a session's deferred predictions pulled
-to the host) inside ``serve.call``; and ``serve.session``, from a
-session's first ingest to its finish, which spans rounds and so is kept
-in the ring only. A span's ``id`` is the session's sid, or a flush's
+put), ``serve.gate`` (the mask cache's walk, MGNet's input, score and
+select launches, and the score pull where one happens), ``serve.route``
+(embed, budget, order and gather launches, the batcher push) and
+``serve.flush`` (place, encode launch, argmax, bookkeeping);
+``serve.finish`` (a session's deferred predictions pulled to the host)
+inside ``serve.call``; and ``serve.session``, from a session's first
+ingest to its finish, which spans rounds and so is kept in the ring
+only. A span's ``id`` is the session's sid, or a flush's
 sequence number, whose bucket and owning sids ride along.
 
 Ring times are nanoseconds on the profiler's clock (the host's real-time
@@ -44,8 +49,8 @@ import jax
 
 __all__ = ["Span", "Spans"]
 
-COUNTERS = ("chunks_ingested", "h2d_bytes", "score_launches", "host_syncs",
-            "flushes", "rows_launched", "rows_real")
+COUNTERS = ("chunks_ingested", "h2d_bytes", "score_launches", "gate_pulls",
+            "host_syncs", "flushes", "rows_launched", "rows_real")
 
 
 class Span(NamedTuple):

@@ -405,6 +405,52 @@ def test_mesh_sharded_encode_matches_single_device():
     assert outs["auto"] == outs["off"]
 
 
+_PINNED_MESH_SCRIPT = """
+import json, sys
+from repro.data.pipeline import video_fleet
+from repro.serving.engine import _smoke_cfg
+from repro.serving.server import ServerConfig, StreamServer
+mode = sys.argv[1]
+cfg = _smoke_cfg("photonic_sim")
+srv = StreamServer(cfg, ServerConfig(microbatch=4, chunk=8, mesh=mode,
+                                     force_bucket=0.5), n_classes=8)
+sessions = [srv.add_session(st, n_frames=16)
+            for st in video_fleet(2, img_size=32, patch=8, seed=0,
+                                  cut_every=16)]
+srv.serve(max_rounds=1)
+# mid-stream, the reference scores sit on every device of the mesh
+ref = sessions[0].cache._ref_scores
+on = len(ref.sharding.device_set)
+res = srv.serve()
+print(json.dumps({"predictions": {str(s.sid): res[s.sid].predictions
+                                  for s in sessions},
+                  "ref_devices": on,
+                  "gate_pulls": srv.spans.gate_pulls}))
+"""
+
+
+@pytest.mark.slow
+def test_mesh_device_gate_matches_single_device():
+    """The gate's device programs (MGNet's input gather, the score
+    selection) run whole on each device of a forced 2-device data mesh,
+    warmed at start-up: predictions equal the single-device run's, and
+    under a pin no score crosses to the host."""
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
+               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
+                          " --xla_force_host_platform_device_count=2"))
+    outs = {}
+    for mode in ("auto", "off"):
+        proc = subprocess.run(
+            [sys.executable, "-c", _PINNED_MESH_SCRIPT, mode],
+            capture_output=True, text=True, timeout=600, env=env,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        outs[mode] = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert outs["auto"]["predictions"] == outs["off"]["predictions"]
+    assert (outs["auto"]["ref_devices"], outs["off"]["ref_devices"]) == (2, 1)
+    assert [o["gate_pulls"] for o in outs.values()] == [0, 0]
+
+
 _MODEL_MESH_SCRIPT = """
 import json, sys
 import jax

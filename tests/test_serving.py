@@ -14,7 +14,7 @@ from repro.data.pipeline import VideoStream, prefetch_to_device
 from repro.serving.accounting import StreamAccounting
 from repro.serving.buckets import BucketHistogram, BucketLadder
 from repro.serving.engine import ServingConfig, ServingEngine
-from repro.serving.mask_cache import TemporalMaskCache
+from repro.serving.mask_cache import GateOps, TemporalMaskCache
 from repro.serving.scheduler import MicroBatcher
 
 
@@ -179,6 +179,123 @@ def test_mask_cache_static_score_shape():
     for c in range(4):
         cache.gate(_static_frames(8), np.arange(8 * c, 8 * c + 8), score_fn)
     assert shapes == {(8, 8, 8, 3)}
+
+
+_OPS = GateOps.build()
+
+
+@jax.jit
+def _jit_score(f):
+    # a stand-in MGNet: per-frame rows that depend on every pixel
+    flat = f.reshape(f.shape[0], -1)
+    return jnp.tanh(flat[:, :4] * 3.0 + flat.mean(axis=1, keepdims=True))
+
+
+def _chunks(kind, n_chunks=4, c=8, h=8, seed=0):
+    """(frames, frame_idx, eligible) chunks of one stream: ``drift`` moves
+    a little every frame, ``cut`` adds a scene cut inside chunk 1, ``tail``
+    leaves the last chunk's final frames ineligible."""
+    rng = np.random.default_rng(seed)
+    base = rng.random((h, h, 3)).astype(np.float32)
+    step = 0.05 if kind == "drift" else 0.01
+    out = []
+    for k in range(n_chunks):
+        f = np.stack([base + step * (k * c + i) for i in range(c)]
+                     ).astype(np.float32)
+        if kind == "cut" and k == 1:
+            f[3:] += 2.0
+        idx = np.arange(k * c, (k + 1) * c)
+        elig = None
+        if kind == "tail" and k == n_chunks - 1:
+            elig = idx < k * c + 5
+        out.append((f, idx, elig))
+    return out
+
+
+@pytest.mark.parametrize("kind,refresh,thr", [
+    ("drift", 4, 1e9),       # the staleness bound alone
+    ("drift", 1000, 0.12),   # the delta trigger alone
+    ("cut", 16, 0.5),        # a scene cut, and chunks scoring nothing
+    ("tail", 3, 0.3),        # ineligible tail frames
+])
+def test_mask_cache_device_gate_matches_host_bitwise(kind, refresh, thr):
+    """MGNet on the device view, scores kept there: each frame's scores,
+    n_scored and the reference state are bitwise the host path's."""
+    host = TemporalMaskCache(refresh, thr)
+    dev = TemporalMaskCache(refresh, thr)
+    reused = 0
+    for f, idx, elig in _chunks(kind):
+        sh, nh = host.gate(f, idx, _jit_score, eligible=elig)
+        sd, nd = dev.gate(f, idx, _jit_score, eligible=elig,
+                          device_frames=jnp.asarray(f), ops=_OPS)
+        assert isinstance(sh, np.ndarray) and isinstance(sd, jax.Array)
+        assert nd == nh
+        reused += nh == 0
+        np.testing.assert_array_equal(np.asarray(sd), sh)
+        assert sd.dtype == sh.dtype == np.float32
+        assert isinstance(dev._ref_scores, jax.Array)
+        hs, ds = host.state_dict(), dev.state_dict()
+        assert hs.keys() == ds.keys()
+        for k in hs:
+            np.testing.assert_array_equal(ds[k], hs[k])
+    assert (dev.scored_frames, dev.reused_frames) == \
+        (host.scored_frames, host.reused_frames)
+    if kind == "cut":
+        assert reused                     # a chunk that scored nothing
+
+
+def test_mask_cache_host_scores_pull_only_scored_chunks():
+    """``host_scores`` crosses for a scored chunk and reuses the kept host
+    row for one that scored nothing — and is what the gate selected."""
+    cache = TemporalMaskCache(refresh=16, delta_threshold=1e9)
+    pulls = []
+    for f, idx, _ in _chunks("drift"):
+        sd, n = cache.gate(f, idx, _jit_score, device_frames=jnp.asarray(f),
+                           ops=_OPS)
+        sh, pulled = cache.host_scores(sd, n)
+        pulls.append(pulled)
+        np.testing.assert_array_equal(sh, np.asarray(sd))
+    assert pulls == [True, False, True, False]
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64, np.uint8])
+def test_mask_cache_delta_buffer_is_frame_delta_bitwise(dtype):
+    from repro.core.mgnet import frame_delta
+    rng = np.random.default_rng(3)
+    cache = TemporalMaskCache()
+    for _ in range(6):
+        a = (rng.random((32, 32, 3)) * 4).astype(dtype)
+        b = (rng.random((32, 32, 3)) * 4).astype(dtype)
+        assert cache._delta(a, b) == float(frame_delta(a[None], b)[0])
+
+
+@pytest.mark.parametrize("on_device", [False, True])
+def test_mask_cache_walk_allocates_no_frame_temporaries(on_device):
+    """A warm gate call over 224 px frames allocates less than two frames:
+    the deltas go through one reused buffer, MGNet's host input through
+    another, and the device path builds none."""
+    import tracemalloc
+    rng = np.random.default_rng(4)
+    f = rng.random((8, 224, 224, 3)).astype(np.float32)
+    dev = jnp.asarray(f) if on_device else None
+    cache = TemporalMaskCache(refresh=4, delta_threshold=1e9)
+
+    def score(x):
+        return (_jit_score(x) if on_device
+                else np.zeros((x.shape[0], 4), np.float32))
+
+    for k in range(2):                    # cold, then warm: every program
+        cache.gate(f, np.arange(8 * k, 8 * k + 8), score,
+                   device_frames=dev, ops=_OPS)
+    tracemalloc.start()
+    try:
+        _, n = cache.gate(f, np.arange(16, 24), score, device_frames=dev,
+                          ops=_OPS)
+        peak = tracemalloc.get_traced_memory()[1]
+    finally:
+        tracemalloc.stop()
+    assert n == 2                         # frames 16 and 20 re-scored
+    assert peak < 2 * f[0].nbytes, peak
 
 
 # --------------------------------------------------------------------------

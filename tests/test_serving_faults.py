@@ -272,6 +272,37 @@ def test_checkpoint_roundtrip_bitwise(tmp_path, base3):
         assert res[sid].mean_frame_uj == base[sid].mean_frame_uj
 
 
+@pytest.mark.parametrize("force_bucket,refresh", [(0.5, 8), (0.5, 16),
+                                                   (0.0, 16)])
+def test_checkpoint_roundtrip_device_ref_scores_bitwise(tmp_path, base3,
+                                                        force_bucket,
+                                                        refresh):
+    """The mask caches' reference scores live on the device mid-stream; a
+    checkpoint pulls them, and the restored serve (host reference scores
+    until the next scored chunk) resumes bitwise, pinned or score-driven,
+    through chunks that score nothing (refresh 16 over chunks of 8)."""
+    import jax
+    cfg, streams, _, _ = base3
+    kw = dict(force_bucket=force_bucket, mask_refresh=refresh,
+              delta_threshold=1e9 if refresh > 8 else 0.15)
+    whole = _serve(_server(cfg, **kw), streams)
+    srv = _server(cfg, **kw)
+    live = [srv.add_session(st, n_frames=N_FRAMES) for st in streams]
+    assert srv.serve(max_rounds=1) == {}
+    assert all(isinstance(s.cache._ref_scores, jax.Array) for s in live)
+    srv.checkpoint(root=str(tmp_path))
+    srv2 = _server(cfg, **kw)
+    srv2.restore_checkpoint(str(tmp_path), streams=dict(enumerate(streams)))
+    res = srv2.serve()
+    for sid, r in whole.items():
+        np.testing.assert_array_equal(_preds(res[sid]), _preds(r))
+        assert res[sid].scored_frames == r.scored_frames
+        assert res[sid].reused_frames == r.reused_frames
+    pulls = srv.spans.gate_pulls + srv2.spans.gate_pulls
+    launches = srv.spans.score_launches + srv2.spans.score_launches
+    assert pulls == (0 if force_bucket else launches)
+
+
 @pytest.mark.slow
 def test_checkpoint_roundtrip_noisy_drift_bitwise(tmp_path):
     """Under calibrated noise the server-owned DriftState (thermal time

@@ -129,17 +129,44 @@ def test_counters_agree_with_results_and_flush_log(cfg, streams):
     n_chunks = 3 * len(sess)
     assert sp.chunks_ingested == n_chunks
     frame_bytes = 8 * cfg.img_size * cfg.img_size * 3 * 4
-    score_bytes = 8 * srv.n_patches * 4
-    assert sp.h2d_bytes == n_chunks * (frame_bytes + score_bytes)
+    # the frames are put once; the scores never go back to the device
+    assert sp.h2d_bytes == n_chunks * frame_bytes
     assert sp.score_launches == len(launches) > 0
     assert sp.score_launches <= sum(r.scored_frames for r in res.values())
     assert sp.flushes == len(srv.flush_log)
     assert sp.rows_real == sum(n for _, _, n in srv.flush_log) == \
         sum(r.frames for r in res.values())
     assert sp.rows_launched == 4 * sp.flushes
+    # score-driven routing pulls each scored chunk's scores once
+    assert sp.gate_pulls == sp.score_launches
     # untimed: the score pulls and one pull per deferred flush result
-    assert sp.host_syncs == sp.score_launches + sp.flushes
+    assert sp.host_syncs == sp.gate_pulls + sp.flushes
     assert sp.counts()["flushes"] == sp.flushes
+
+
+@pytest.mark.parametrize("refresh,thr", [(8, 0.15), (16, 1e9)])
+def test_pinned_gate_pulls_nothing(cfg, streams, refresh, thr):
+    """Under a ``force_bucket`` pin the scores never cross to the host:
+    the only host syncs are the deferred-prediction pulls. Score-driven
+    routing on a one-rung ladder (every frame to the same bucket) serves
+    bitwise the same classes and pulls once per scored chunk — not for a
+    chunk that scored nothing (refresh 16 over chunks of 8)."""
+    kw = dict(bucket_fractions=(1.0,), mask_refresh=refresh,
+              delta_threshold=thr)
+    pinned = _server(cfg, force_bucket=1.0, **kw)
+    _, res_p = _serve(pinned, streams)
+    sp = pinned.spans
+    assert sp.gate_pulls == 0
+    assert sp.host_syncs == sp.flushes
+    driven = _server(cfg, **kw)
+    _, res_d = _serve(driven, streams)
+    sd = driven.spans
+    assert sd.gate_pulls == sd.score_launches == sp.score_launches > 0
+    assert sd.host_syncs == sd.gate_pulls + sd.flushes
+    if refresh > 8:
+        assert sd.score_launches < sd.chunks_ingested
+    assert [sorted(p.items()) for p in _preds(res_p).values()] == \
+        [sorted(p.items()) for p in _preds(res_d).values()]
 
 
 def test_timed_flushes_feed_telemetry_from_the_flush_span(cfg, streams):
@@ -150,7 +177,7 @@ def test_timed_flushes_feed_telemetry_from_the_flush_span(cfg, streams):
     assert all(o.wall_s > 0 for o in obs)
     sp = srv.spans
     # every timed flush adds its block_until_ready to the host syncs
-    assert sp.host_syncs == sp.score_launches + 2 * sp.flushes
+    assert sp.host_syncs == sp.gate_pulls + 2 * sp.flushes
     assert len(sp.ring) == 0              # timed, yet recorded nothing
 
 
@@ -215,6 +242,11 @@ def test_jitted_programs_lower_under_stable_names(cfg):
     lowered = {
         "opto_embed": srv._embed.lower(srv.params, zf),
         "mgnet_score": srv._score.lower(srv.params, zf),
+        "gate_inputs": srv._gate_ops.inputs.lower(
+            zf, jnp.zeros(sc.chunk, jnp.int32)),
+        "gate_scores": srv._gate_ops.scores.lower(
+            jnp.zeros(n), jnp.zeros((sc.chunk, n)),
+            jnp.zeros(sc.chunk, jnp.int32)),
         "patch_order": srv._order.lower(jnp.zeros((sc.chunk, n))),
         "gather_topk": srv._gather[k].lower(zt, order),
         "opto_encode": srv._encode.lower(srv.params, enc),

@@ -191,3 +191,25 @@ def test_gate_compiles_whole_on_each_device(serving_mesh):
         params, frames)
     assert embed == {"photonic_matmul": 1}, embed
     assert gate == {"photonic_matmul": 8}, gate
+
+
+def test_gate_device_programs_compile_on_each_device(serving_mesh):
+    """MGNet's padded input gathered from the chunk on the device and the
+    per-frame score selection, whole on each device of a mesh, at 224 px:
+    every variant a session meets (no reference yet, a reference with
+    fresh rows, a reference alone)."""
+    from repro.serving.mask_cache import GateOps
+    from repro.serving.server import _whole_on_each_device
+    mesh = serving_mesh
+    rep = NamedSharding(mesh, P())
+    ops = GateOps.build(lambda f: _whole_on_each_device(f, mesh))
+    frames = _sds((8, 224, 224, 3), jnp.float32, rep)
+    idx = _sds((8,), jnp.int32, rep)
+    ref = _sds((N - 1,), jnp.float32, rep)
+    fresh = _sds((8, N - 1), jnp.float32, rep)
+    sub = ops.inputs.lower(frames, idx).compile()
+    assert sub.output_shardings.is_equivalent_to(rep, 4)
+    for r, f in ((None, fresh), (ref, fresh), (ref, None)):
+        out = ops.scores.lower(r, f, idx).compile()
+        assert [s.is_equivalent_to(rep, o) for s, o in
+                zip(out.output_shardings, (2, 1))] == [True, True]
