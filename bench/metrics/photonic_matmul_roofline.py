@@ -1,7 +1,8 @@
 """``photonic_matmul``'s share of its roofline, in %: the least time the chip
-could take for the work the served frames need of this kernel
-(``bench/ops.py``), over the kernel's summed device time in the trace.
-Nothing when the trace shows no such kernel."""
+could take for the work the served frames need of this kernel (the
+cell's model module's ``frame_work``, ``bench/models/``), over the
+kernel's summed device time in the trace. Nothing when the trace
+shows no such kernel."""
 
 KERNEL = "photonic_matmul"
 

@@ -3,33 +3,34 @@ mathematics at the configuration's shapes.
 
 Nothing here looks at a kernel. Rows are real rows only (a flush's
 padding frames, a score launch's zero frames and the kernels' tile
-padding are never counted), and each FFN matmul is counted once (the
-fused FFN kernel's recompute of ``x @ w1`` is not work the frame needs).
-So a later implementation that drops padding or recompute reads a higher
-share, and none can read above 100%.
+padding are never counted), and each matmul is counted once (a fused
+kernel's recompute is not work the frame needs). So a later
+implementation that drops padding or recompute reads a higher share,
+and none can read above 100%.
 
 Bytes are what one launch must move at least: its int8 activation and
 weight operands once, its float32 result once (and a float kernel's
 float32 operands once). Weights are counted once per launch, because a
 launch cannot avoid reading them.
 
-Kernel keys are the Pallas kernel names the trace shows:
-``photonic_matmul`` (every int8 linear: patch embed, MGNet, the encoder's
-q/k/v/o projections and the head), ``flash_attention_masked`` (the
-encoder's softmax(QK^T)V core) and ``fused_ffn`` (the encoder's GELU
-MLP). ``other`` holds needed work that runs outside those kernels
-(MGNet's float attention), which only the whole-step share counts.
+Work is kept by the Pallas kernel name the trace shows. The counts here
+are the parts every model served behind the RoI gate shares: the patch
+embed and MGNet, whose int8 linears run as ``MATMUL``, and MGNet's float
+attention under ``other`` (needed work outside any kernel, which only
+the whole-step share counts). A model module (``bench/models/``) counts
+its encoder and head from these pieces, under the kernel names it
+declares in its ``KERNELS``.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 
-__all__ = ["Tally", "Work", "matmul_int8", "embed_work", "mgnet_work",
-           "encode_work", "KERNELS"]
+__all__ = ["Tally", "Work", "matmul_int8", "attention_float", "embed_work",
+           "mgnet_work", "MATMUL", "F32"]
 
-KERNELS = ("photonic_matmul", "flash_attention_masked", "fused_ffn")
-_F32 = 4
+MATMUL = "photonic_matmul"
+F32 = 4
 
 
 @dataclass
@@ -80,13 +81,13 @@ class Work:
 def matmul_int8(m: int, k: int, n: int) -> Tally:
     """(m, k) int8 @ (k, n) int8 -> (m, n) float32, per-channel scales."""
     return Tally(int8_ops=2.0 * m * k * n,
-                 bytes=m * k + k * n + _F32 * (m * n + n))
+                 bytes=m * k + k * n + F32 * (m * n + n))
 
 
-def _attention_float(n: int, d: int) -> Tally:
+def attention_float(n: int, d: int) -> Tally:
     """softmax(QK^T)V over all heads of one sequence of n tokens, float32
     operands and result: two (n, d, n)-shaped contractions."""
-    return Tally(float_ops=2.0 * 2.0 * n * n * d, bytes=_F32 * 4 * n * d)
+    return Tally(float_ops=2.0 * 2.0 * n * n * d, bytes=F32 * 4 * n * d)
 
 
 def embed_work(cfg: dict, frames: int) -> Work:
@@ -94,7 +95,7 @@ def embed_work(cfg: dict, frames: int) -> Work:
     n = (cfg["img_size"] // cfg["patch"]) ** 2
     p_in = 3 * cfg["patch"] ** 2
     w = Work()
-    w.add("photonic_matmul", matmul_int8(frames * n, p_in, cfg["d_model"]))
+    w.add(MATMUL, matmul_int8(frames * n, p_in, cfg["d_model"]))
     return w
 
 
@@ -115,25 +116,8 @@ def mgnet_work(cfg: dict, frames: int) -> Work:
                      (1, d, d),              # score q of [cls]
                      (n, d, d),              # score keys
                      (1, n, n)):             # region head
-        w.add("photonic_matmul", matmul_int8(frames * m, k, nn))
-    att = _attention_float(t, d)
+        w.add(MATMUL, matmul_int8(frames * m, k, nn))
+    att = attention_float(t, d)
     att.add(Tally(float_ops=2.0 * n * d))    # q_cls . K^T
     w.add("other", att, frames)
-    return w
-
-
-def encode_work(cfg: dict, kept: int, frames: int) -> Work:
-    """Encoder + head for ``frames`` real frames of ``kept`` patches each
-    (the [cls] token rides along)."""
-    d, dff, layers = cfg["d_model"], cfg["d_ff"], cfg["n_layers"]
-    t = kept + 1
-    rows = frames * t
-    w = Work()
-    for _ in range(4):                       # q, k, v, o projections
-        w.add("photonic_matmul", matmul_int8(rows, d, d), layers)
-    w.add("flash_attention_masked", _attention_float(t, d), frames * layers)
-    ffn = Tally(int8_ops=2.0 * 2.0 * rows * d * dff,
-                bytes=rows * d + 2 * d * dff + _F32 * (dff + d + rows * d))
-    w.add("fused_ffn", ffn, layers)
-    w.add("photonic_matmul", matmul_int8(frames, d, cfg["n_classes"]))
     return w

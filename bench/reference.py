@@ -1,4 +1,5 @@
-"""Plain float32 reference of the served model, and the weights both use.
+"""The plain float32 reference's shared parts, and the weights it and the
+program both use.
 
 Written from the model's equations (Opto-ViT, arXiv:2507.07044: MGNet
 region scores, top-k patch selection, a ViT encoder; ViT,
@@ -6,7 +7,16 @@ arXiv:2010.11929), with none of the program's code: no backend, kernels,
 quantization, mask cache or batching. Every matmul runs at float32
 ``highest`` precision, one frame's computation never sees another's.
 
-``init_params`` makes the random weights from the seed, on the device, in
+What differs between models lives in the model module a configuration
+names (``bench/models/<model>.py``, see ``bench/models/__init__.py``):
+its parameter tree (``init_params``) and its forward pass
+(``classify``). What every model served behind the RoI gate shares lives
+here: the MGNet score pass and its weights, the gate's top-k rule, the
+layers' plain equations (``ln``, ``gelu``, ``patches``, ``attention``),
+the seeding (``make_init``), the blocked evaluation (``ReferenceModel``)
+and the comparison (``served_gaps``).
+
+``make_init`` makes the random weights from the seed, on the device, in
 the parameter layout the program takes (``StreamServer(params=...)``).
 The program quantizes them itself; the reference reads the same float32
 arrays.
@@ -17,10 +27,8 @@ of its stream unless ``refresh`` frames have passed since that one was
 scored or the mean absolute pixel difference to it exceeds
 ``threshold``.
 
-Departures from the published ViT, each also the program's stated
-equation: GELU is the tanh form, LayerNorm's epsilon is the
-configuration's ``norm_eps``, the classifier reads the final-normed
-[cls] token with no bias.
+Departure from the published ViT, also the program's stated equation:
+GELU is the tanh form.
 """
 
 from __future__ import annotations
@@ -29,87 +37,61 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["init_params", "make_init", "scoring_frames", "ReferenceModel",
-           "served_gaps"]
+__all__ = ["dense", "near", "init_mgnet", "make_init", "scoring_frames",
+           "ln", "gelu", "patches", "attention", "mgnet_scores",
+           "top_patches", "ReferenceModel", "served_gaps"]
 
 
-def _dense(key, fan_in, shape, gain=1.0):
+# -- weights -----------------------------------------------------------------
+
+def dense(key, fan_in, shape, gain=1.0):
     return jax.random.normal(key, shape, jnp.float32) * (
         gain / np.sqrt(fan_in))
 
 
-def _near(key, shape, center, spread):
+def near(key, shape, center, spread):
     return center + spread * jax.random.normal(key, shape, jnp.float32)
 
 
-def init_params(key, cfg: dict) -> dict:
-    """Random float32 weights in the program's layout. Biases, LayerNorm
-    gains and shifts are drawn too (not zeros and ones), so that the
-    comparison covers them."""
-    d, dff, L = cfg["d_model"], cfg["d_ff"], cfg["n_layers"]
+def init_mgnet(nk, cfg: dict) -> dict:
+    """MGNet's weights, each drawn with the next key ``nk()`` gives.
+    Biases and LayerNorm gains and shifts are drawn too (not zeros and
+    ones), so that the comparison covers them."""
     n = (cfg["img_size"] // cfg["patch"]) ** 2
     p_in = 3 * cfg["patch"] ** 2
     md = cfg["mgnet_embed"]
     mff = int(md * cfg["mgnet_mlp_ratio"])
-    ks = iter(jax.random.split(key, 40))
-    he = float(np.sqrt(2.0))
-
-    def nk():
-        return next(ks)
-
-    blocks = {
-        "ln1_g": _near(nk(), (L, d), 1.0, 0.1),
-        "ln1_b": _near(nk(), (L, d), 0.0, 0.02),
-        "attn": {name: _dense(nk(), d, (L, d, d), he)
-                 for name in ("wq", "wk", "wv", "wo")},
-        "ln2_g": _near(nk(), (L, d), 1.0, 0.1),
-        "ln2_b": _near(nk(), (L, d), 0.0, 0.02),
-        "ffn": {"w1": _dense(nk(), d, (L, d, dff), he),
-                "b1": _near(nk(), (L, dff), 0.0, 0.02),
-                "w2": _dense(nk(), dff, (L, dff, d), he),
-                "b2": _near(nk(), (L, d), 0.0, 0.02)},
-    }
-    mgnet = {
-        "patch_embed": {"w": _dense(nk(), p_in, (p_in, md)),
-                        "b": _near(nk(), (md,), 0.0, 0.02)},
-        "cls_token": _near(nk(), (1, 1, md), 0.0, 0.02),
-        "pos_embed": _near(nk(), (1, n + 1, md), 0.0, 0.02),
-        "block": {
-            "ln1": {"g": _near(nk(), (md,), 1.0, 0.1),
-                    "b": _near(nk(), (md,), 0.0, 0.02)},
-            "wqkv": _dense(nk(), md, (md, 3 * md)),
-            "wo": _dense(nk(), md, (md, md)),
-            "ln2": {"g": _near(nk(), (md,), 1.0, 0.1),
-                    "b": _near(nk(), (md,), 0.0, 0.02)},
-            "w1": _dense(nk(), md, (md, mff)),
-            "b1": _near(nk(), (mff,), 0.0, 0.02),
-            "w2": _dense(nk(), mff, (mff, md)),
-            "b2": _near(nk(), (md,), 0.0, 0.02),
-        },
-        "score": {"wq": _dense(nk(), md, (md, md)),
-                  "wk": _dense(nk(), md, (md, md)),
-                  "head_w": _dense(nk(), n, (n, n)),
-                  "head_b": _near(nk(), (n,), 0.0, 0.02)},
-    }
     return {
-        "patch_embed": {"w": _dense(nk(), p_in, (p_in, d), he),
-                        "b": _near(nk(), (d,), 0.0, 0.02)},
-        "cls": _near(nk(), (1, 1, d), 0.0, 0.02),
-        "pos": _near(nk(), (1, n + 1, d), 0.0, 0.02),
-        "blocks": blocks,
-        "final_ln_g": _near(nk(), (d,), 1.0, 0.1),
-        "final_ln_b": _near(nk(), (d,), 0.0, 0.02),
-        "head": _dense(nk(), d, (d, cfg["n_classes"]), he),
-        "mgnet": mgnet,
+        "patch_embed": {"w": dense(nk(), p_in, (p_in, md)),
+                        "b": near(nk(), (md,), 0.0, 0.02)},
+        "cls_token": near(nk(), (1, 1, md), 0.0, 0.02),
+        "pos_embed": near(nk(), (1, n + 1, md), 0.0, 0.02),
+        "block": {
+            "ln1": {"g": near(nk(), (md,), 1.0, 0.1),
+                    "b": near(nk(), (md,), 0.0, 0.02)},
+            "wqkv": dense(nk(), md, (md, 3 * md)),
+            "wo": dense(nk(), md, (md, md)),
+            "ln2": {"g": near(nk(), (md,), 1.0, 0.1),
+                    "b": near(nk(), (md,), 0.0, 0.02)},
+            "w1": dense(nk(), md, (md, mff)),
+            "b1": near(nk(), (mff,), 0.0, 0.02),
+            "w2": dense(nk(), mff, (mff, md)),
+            "b2": near(nk(), (md,), 0.0, 0.02),
+        },
+        "score": {"wq": dense(nk(), md, (md, md)),
+                  "wk": dense(nk(), md, (md, md)),
+                  "head_w": dense(nk(), n, (n, n)),
+                  "head_b": near(nk(), (n,), 0.0, 0.02)},
     }
 
 
-def make_init(cfg: dict, seed: int):
-    """The whole weight tree from ``seed`` in one jitted call."""
+def make_init(model, cfg: dict, seed: int):
+    """The whole weight tree of ``model`` (a model module) from ``seed``
+    in one jitted call."""
     state = np.random.SeedSequence(int(seed)).generate_state(2, np.uint32)
     key = jax.random.fold_in(jax.random.PRNGKey(int(state[0])),
                              int(state[1] >> 1))
-    return jax.jit(lambda k: init_params(k, cfg))(key)
+    return jax.jit(lambda k: model.init_params(k, cfg))(key)
 
 
 # -- the mask-cache rule -----------------------------------------------------
@@ -132,26 +114,26 @@ def scoring_frames(frames: np.ndarray, refresh: int,
     return out
 
 
-# -- the forward pass --------------------------------------------------------
+# -- the layers' equations ---------------------------------------------------
 
-def _ln(x, g, b, eps):
+def ln(x, g, b, eps):
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
     return (x - mu) / jnp.sqrt(var + eps) * g + b
 
 
-def _gelu(x):
+def gelu(x):
     return 0.5 * x * (1.0 + jnp.tanh(np.sqrt(2.0 / np.pi)
                                      * (x + 0.044715 * x ** 3)))
 
 
-def _patches(images, p):
+def patches(images, p):
     b, h, w, c = images.shape
     x = images.reshape(b, h // p, p, w // p, p, c).transpose(0, 1, 3, 2, 4, 5)
     return x.reshape(b, (h // p) * (w // p), p * p * c)
 
 
-def _attention(x, wq, wk, wv, heads):
+def attention(x, wq, wk, wv, heads):
     b, n, d = x.shape
     dh = d // heads
 
@@ -163,20 +145,23 @@ def _attention(x, wq, wk, wv, heads):
     return (a @ v).transpose(0, 2, 1, 3).reshape(b, n, d)
 
 
-def _mgnet_scores(mp, images, cfg):
+# -- the RoI gate ------------------------------------------------------------
+
+def mgnet_scores(mp, images, cfg):
+    """MGNet's region score of every patch of ``images``."""
     md = cfg["mgnet_embed"]
     eps = 1e-6                      # MGNet's own LayerNorm epsilon
-    x = _patches(images, cfg["patch"]) @ mp["patch_embed"]["w"] \
+    x = patches(images, cfg["patch"]) @ mp["patch_embed"]["w"] \
         + mp["patch_embed"]["b"]
     b = x.shape[0]
     cls = jnp.broadcast_to(mp["cls_token"], (b, 1, md))
     x = jnp.concatenate([cls, x], axis=1) + mp["pos_embed"]
     blk = mp["block"]
     wq, wk, wv = jnp.split(blk["wqkv"], 3, axis=-1)
-    h = _ln(x, blk["ln1"]["g"], blk["ln1"]["b"], eps)
-    x = x + _attention(h, wq, wk, wv, cfg["mgnet_heads"]) @ blk["wo"]
-    h = _ln(x, blk["ln2"]["g"], blk["ln2"]["b"], eps)
-    x = x + _gelu(h @ blk["w1"] + blk["b1"]) @ blk["w2"] + blk["b2"]
+    h = ln(x, blk["ln1"]["g"], blk["ln1"]["b"], eps)
+    x = x + attention(h, wq, wk, wv, cfg["mgnet_heads"]) @ blk["wo"]
+    h = ln(x, blk["ln2"]["g"], blk["ln2"]["b"], eps)
+    x = x + gelu(h @ blk["w1"] + blk["b1"]) @ blk["w2"] + blk["b2"]
     s = mp["score"]
     q_cls = x[:, :1] @ s["wq"]
     keys = x[:, 1:] @ s["wk"]
@@ -184,41 +169,22 @@ def _mgnet_scores(mp, images, cfg):
     return s_cls @ s["head_w"] + s["head_b"]
 
 
-def _classify(p, images, score_images, keep, cfg):
-    """Logits of ``images`` whose kept patches are chosen by the region
-    scores of ``score_images`` (the frames whose scores they reuse)."""
-    eps = cfg["norm_eps"]
-    scores = _mgnet_scores(p["mgnet"], score_images, cfg)
-    # highest scores first; among equal scores the lower patch index
-    order = jnp.argsort(-scores, axis=-1, stable=True)[:, :keep]
-    x = _patches(images, cfg["patch"]) @ p["patch_embed"]["w"] \
-        + p["patch_embed"]["b"] + p["pos"][:, 1:]
-    x = jnp.take_along_axis(x, order[:, :, None], axis=1)
-    b, _, d = x.shape
-    cls = jnp.broadcast_to(p["cls"] + p["pos"][:, :1], (b, 1, d))
-    x = jnp.concatenate([cls, x], axis=1)
+def top_patches(scores, keep: int):
+    """The ``keep`` patches a frame keeps, in order: highest scores first;
+    among equal scores the lower patch index."""
+    return jnp.argsort(-scores, axis=-1, stable=True)[:, :keep]
 
-    def layer(x, lp):
-        h = _ln(x, lp["ln1_g"], lp["ln1_b"], eps)
-        a = lp["attn"]
-        x = x + _attention(h, a["wq"], a["wk"], a["wv"],
-                           cfg["n_heads"]) @ a["wo"]
-        h = _ln(x, lp["ln2_g"], lp["ln2_b"], eps)
-        f = lp["ffn"]
-        x = x + _gelu(h @ f["w1"] + f["b1"]) @ f["w2"] + f["b2"]
-        return x, None
 
-    x, _ = jax.lax.scan(layer, x, p["blocks"])
-    x = _ln(x[:, 0], p["final_ln_g"], p["final_ln_b"], eps)
-    return x @ p["head"]
-
+# -- evaluation --------------------------------------------------------------
 
 class ReferenceModel:
-    """Logits of chosen frames, computed in blocks of ``block`` frames."""
+    """Logits of chosen frames by ``model``'s ``classify`` (a model
+    module), computed in blocks of ``block`` frames."""
 
-    def __init__(self, params: dict, cfg: dict, keep: int, block: int = 32):
+    def __init__(self, model, params: dict, cfg: dict, keep: int,
+                 block: int = 32):
         self.params, self.cfg, self.keep, self.block = params, cfg, keep, block
-        self._fn = jax.jit(lambda p, x, s: _classify(p, x, s, keep, cfg))
+        self._fn = jax.jit(lambda p, x, s: model.classify(p, x, s, keep, cfg))
 
     def logits(self, images: np.ndarray, score_images: np.ndarray
                ) -> np.ndarray:

@@ -4,20 +4,25 @@
 
 The cell's configuration, traffic and chip count come from
 ``BENCHMARK.json``; its server settings and correctness limits from
-``bench/workloads/<cell>.json``. Set-up makes the weights on the device
-from the seed, builds ``StreamServer``, compiles the cell's one bucket
-and serves one untimed round of the traffic. The window then drives
-``StreamServer.serve()`` for ``--seconds``. With ``--trace 1`` the
-cell's per-layer metrics are printed: counters, clip spans and
-``serve_mfu`` from the window, device numbers from about three more
-seconds of the same traffic served under a device-only profiler trace
-after it; with ``--trace 0`` its end-to-end metrics.
+``bench/workloads/<cell>.json``. The configuration file names its model
+(``"model"``), whose module ``bench/models/<model>.py`` gives the
+program's configuration, the seeded weights, the float32 forward pass and
+the operation counts (``bench/models/__init__.py`` lists the interface);
+everything else here is the same for every model. Set-up makes the
+weights on the device from the seed, builds ``StreamServer``, compiles
+the cell's one bucket and serves one untimed round of the traffic. The
+window then drives ``StreamServer.serve()`` for ``--seconds``. With
+``--trace 1`` the cell's per-layer metrics are printed: counters, clip
+spans and ``serve_mfu`` from the window, device numbers from about three
+more seconds of the same traffic served under a device-only profiler
+trace after it; with ``--trace 0`` its end-to-end metrics.
 
 After the window (and after the peak device memory was read and the
 server freed) a sample of the served frames, drawn from the seed, is
-checked against the plain float32 reference (``bench/reference.py``).
-The numbers compared are printed on stderr as the last lines, and in the
-result line under ``checks``. The result line is the last line of stdout.
+checked against the plain float32 reference (``bench/reference.py`` with
+the model's ``classify``). The numbers compared are printed on stderr as
+the last lines, and in the result line under ``checks``. The result line
+is the last line of stdout.
 
 Exits non-zero, printing no result, when JAX's devices are not TPUs or
 are fewer than the cell asks for.
@@ -30,6 +35,7 @@ import contextlib
 import gc
 import importlib.util
 import json
+import re
 import shutil
 import statistics
 import sys
@@ -57,6 +63,7 @@ TRACE_DIR = ROOT / ".bench_trace"
 TRACER_LEVELS = {"tpu": {"python_tracer_level": 0, "host_tracer_level": 0},
                  "cpu": {"python_tracer_level": 0, "host_tracer_level": 2}}
 TRACE_SLICE_S = 3.0     # traffic traced after a --trace 1 window
+_MODEL_NAME = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
 
 
 class NoChip(RuntimeError):
@@ -67,6 +74,27 @@ def _merge(base: dict, extra: dict | None) -> dict:
     out = dict(base)
     out.update(extra or {})
     return out
+
+
+def _load(path: Path, module_name: str):
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_model(cfg: dict, cfg_file: Path, root: Path = ROOT):
+    """The model module ``bench/models/<model>.py`` that a configuration
+    names by its ``"model"`` key."""
+    name = cfg.get("model")
+    if not isinstance(name, str) or not _MODEL_NAME.fullmatch(name):
+        raise ValueError(f"{cfg_file}: \"model\" must name a module under "
+                         f"bench/models/, got {name!r}")
+    path = root / "bench" / "models" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"{cfg_file}: model {name!r} has no module "
+                                f"{path}")
+    return _load(path, f"bench_model_{name}")
 
 
 def load_cell(workload: str, root: Path = ROOT,
@@ -82,6 +110,7 @@ def load_cell(workload: str, root: Path = ROOT,
     conf = next(c for c in bench["configs"] if c["name"] == entry["config"])
     with open(root / conf["file"]) as f:
         cfg = _merge(json.load(f), overrides.get("config"))
+    model = load_model(cfg, root / conf["file"], root)
     with open(root / "bench" / "workloads" / f"{workload}.json") as f:
         cell = _merge(json.load(f), overrides.get("cell"))
     traffic = loads.Traffic.load(entry["traffic"], root / "bench")
@@ -93,7 +122,7 @@ def load_cell(workload: str, root: Path = ROOT,
         return "workloads" not in m or workload in m["workloads"]
 
     return {"name": workload, "chips": int(entry["chips"]), "cfg": cfg,
-            "cell": cell, "traffic": traffic,
+            "model": model, "cell": cell, "traffic": traffic,
             "end_to_end": [m for m in bench["end_to_end"] if mine(m)],
             "per_layer": [m for m in bench["per_layer"] if mine(m)]}
 
@@ -108,22 +137,6 @@ def devices_for(chips: int, require_tpu: bool = True) -> list:
         raise NoChip(f"the cell asks for {chips} chips, JAX sees "
                      f"{len(devs)}")
     return devs[:chips]
-
-
-def arch_config(cfg: dict):
-    """The program's configuration object for a configuration file."""
-    from repro.configs.base import ArchConfig
-    b = cfg["backends"]
-    return ArchConfig(
-        name=cfg["name"], family="vit", n_layers=cfg["n_layers"],
-        d_model=cfg["d_model"], n_heads=cfg["n_heads"],
-        kv_heads=cfg["n_heads"], d_ff=cfg["d_ff"], vocab=0,
-        img_size=cfg["img_size"], patch=cfg["patch"],
-        quant_bits=cfg["quant_bits"], mgnet=True,
-        mgnet_embed=cfg["mgnet_embed"], mgnet_heads=cfg["mgnet_heads"],
-        norm_eps=cfg["norm_eps"], remat=False,
-        matmul_backend=b["matmul"], attn_backend=b["attn"],
-        ffn_backend=b["ffn"])
 
 
 def _traced_slice(server, streams: list, t: loads.Traffic, seed: int,
@@ -148,22 +161,14 @@ def _traced_slice(server, streams: list, t: loads.Traffic, seed: int,
     return traced
 
 
-def _window_work(cfg: dict, served: loads.Served) -> ops.Work:
-    work = ops.Work()
-    work.merge(ops.embed_work(cfg, served.frames))
-    work.merge(ops.mgnet_work(cfg, served.scored))
-    for k, n_real in served.flushes:
-        work.merge(ops.encode_work(cfg, k, n_real))
-    return work
+def _window_work(model, cfg: dict, served: loads.Served) -> ops.Work:
+    return model.frame_work(cfg, served.frames, served.scored,
+                            served.flushes)
 
 
 def _reader(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(BENCH / "metrics" / f"{name}.py",
+                 f"bench_metric_{name.replace('.', '_')}").read
 
 
 _COMPILE_EVENTS = ("jaxpr_trace_duration", "backend_compile_duration",
@@ -254,8 +259,8 @@ def check(c: dict, served: loads.Served, streams: list, raw_params,
         score_images.append(
             streams[cam].frames(start + int(walks[s][fi - start]), 1)[0])
         classes.append(served.predictions[s][fi])
-    model = reference.ReferenceModel(raw_params, cfg, keep)
-    ref = model.logits(np.stack(images), np.stack(score_images))
+    ref = reference.ReferenceModel(c["model"], raw_params, cfg, keep).logits(
+        np.stack(images), np.stack(score_images))
     gaps = reference.served_gaps(ref, classes)
     lim = cell["limits"]
     return {
@@ -292,9 +297,9 @@ def setup_cell(c: dict, seed: int, require_tpu: bool = True,
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
     cfg, t, cell = c["cfg"], c["traffic"], c["cell"]
-    raw = reference.make_init(cfg, seed)
+    raw = reference.make_init(c["model"], cfg, seed)
     server = StreamServer(
-        arch_config(cfg),
+        c["model"].arch_config(cfg),
         ServerConfig(warm_start=False,
                      mesh="auto" if c["chips"] > 1 else "off",
                      **cell["server"]),
@@ -347,8 +352,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         breakdown = red.breakdown()
         ctx = {"served": served, "traced": traced, "cfg": cfg,
                "traffic": t, "cell": cell, "chips": len(devs), "trace": red,
-               "work": _window_work(cfg, traced),
-               "window_work": _window_work(cfg, served),
+               "work": _window_work(c["model"], cfg, traced),
+               "window_work": _window_work(c["model"], cfg, served),
                "peaks": (peaks.peaks_for(devs[0].device_kind)
                          if require_tpu else None)}
         for m in c["per_layer"]:

@@ -1,5 +1,6 @@
-"""``bench/ops.py`` and ``bench/peaks.py``: counts at known shapes, no
-padding or recompute counted, unknown chips refused."""
+"""``bench/ops.py``, the ``opto_vit`` model's counts and ``bench/peaks.py``:
+counts at known shapes, no padding or recompute counted, unknown chips
+refused."""
 
 from __future__ import annotations
 
@@ -13,6 +14,7 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 import pytest  # noqa: E402
 
 from bench import ops, peaks  # noqa: E402
+from bench.models import opto_vit  # noqa: E402
 
 BASE = json.loads((ROOT / "bench/configs/opto-vit-base-224.json").read_text())
 LARGE = json.loads(
@@ -34,11 +36,7 @@ def test_int8_matmul_ops_and_bytes():
     (LARGE, 196, 123.4),      # ViT-L/16 at 197 tokens: 24 x 5.116 GOP
 ])
 def test_frame_ops_at_known_shapes(cfg, kept, gop):
-    w = ops.Work()
-    w.merge(ops.embed_work(cfg, 1))
-    w.merge(ops.mgnet_work(cfg, 1))
-    w.merge(ops.encode_work(cfg, kept, 1))
-    tot = w.total()
+    tot = opto_vit.frame_work(cfg, 1, 1, [(kept, 1)]).total()
     assert (tot.int8_ops + tot.float_ops) / 1e9 == pytest.approx(gop,
                                                                  rel=0.005)
 
@@ -46,7 +44,7 @@ def test_frame_ops_at_known_shapes(cfg, kept, gop):
 def test_encoder_counts_by_kernel_base():
     d, dff, L = 768, 3072, 12
     t = 99                                   # 98 kept patches + [cls]
-    w = ops.encode_work(BASE, 98, 1)
+    w = opto_vit.encode_work(BASE, 98, 1)
     pm = w.by_kernel["photonic_matmul"]
     assert pm.int8_ops == L * 4 * 2 * t * d * d + 2 * d * 1000
     assert w.by_kernel["flash_attention_masked"].float_ops == \
@@ -57,9 +55,9 @@ def test_encoder_counts_by_kernel_base():
 def test_only_real_rows_count():
     """A flush of 4 slots with 3 real frames needs exactly three frames'
     work: the padding frame adds nothing."""
-    one = ops.encode_work(BASE, 98, 1)
-    three = ops.encode_work(BASE, 98, 3)
-    for k in ops.KERNELS:
+    one = opto_vit.encode_work(BASE, 98, 1)
+    three = opto_vit.encode_work(BASE, 98, 3)
+    for k in opto_vit.KERNELS:
         assert three.by_kernel[k].int8_ops == pytest.approx(
             3 * one.by_kernel[k].int8_ops)
         assert three.by_kernel[k].float_ops == pytest.approx(
@@ -70,7 +68,7 @@ def test_ffn_counts_each_matmul_once():
     """The fused kernel recomputes x @ w1 per output tile; the count is
     the two matmuls of the layer's mathematics, once each."""
     d, dff, L, t = 768, 3072, 12, 99
-    ffn = ops.encode_work(BASE, 98, 1).by_kernel["fused_ffn"]
+    ffn = opto_vit.encode_work(BASE, 98, 1).by_kernel["fused_ffn"]
     once = 2 * t * d * dff + 2 * t * dff * d
     assert ffn.int8_ops == L * once
 

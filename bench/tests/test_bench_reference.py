@@ -1,5 +1,6 @@
-"""``bench/reference.py`` against what ``StreamServer`` served, at Tiny
-width on the CPU, and its restatement of the mask-cache rule."""
+"""``bench/reference.py`` with the ``opto_vit`` model against what
+``StreamServer`` served, at Tiny width on the CPU, and its restatement of
+the mask-cache rule."""
 
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 from bench import reference, run  # noqa: E402
+from bench.models import opto_vit  # noqa: E402
 from bench.tests.tiny import overrides  # noqa: E402
 
 FLOAT = {"quant_bits": 0,
@@ -38,12 +40,14 @@ def test_reference_is_blocked_and_padded_the_same():
     cfg = json.loads((ROOT / "bench/configs/opto-vit-base-224.json")
                      .read_text())
     cfg.update(overrides("base224-keep33")["config"])
-    params = reference.make_init(cfg, 3)
+    params = reference.make_init(opto_vit, cfg, 3)
     rng = np.random.default_rng(0)
     x = rng.normal(size=(5, 64, 64, 3)).astype(np.float32)
     s = rng.normal(size=(5, 64, 64, 3)).astype(np.float32)
-    a = reference.ReferenceModel(params, cfg, keep=8, block=2).logits(x, s)
-    b = reference.ReferenceModel(params, cfg, keep=8, block=8).logits(x, s)
+    a = reference.ReferenceModel(opto_vit, params, cfg, keep=8,
+                                 block=2).logits(x, s)
+    b = reference.ReferenceModel(opto_vit, params, cfg, keep=8,
+                                 block=8).logits(x, s)
     assert a.shape == (5, 1000)
     np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
 
@@ -52,9 +56,9 @@ def test_weights_follow_the_seed():
     cfg = json.loads((ROOT / "bench/configs/opto-vit-base-224.json")
                      .read_text())
     cfg.update(overrides("base224-keep33")["config"])
-    a = reference.make_init(cfg, 2**33 + 1)
-    b = reference.make_init(cfg, 2**33 + 1)
-    c = reference.make_init(cfg, 2**33 + 2)
+    a = reference.make_init(opto_vit, cfg, 2**33 + 1)
+    b = reference.make_init(opto_vit, cfg, 2**33 + 1)
+    c = reference.make_init(opto_vit, cfg, 2**33 + 2)
     np.testing.assert_array_equal(a["head"], b["head"])
     assert not np.array_equal(a["head"], c["head"])
 

@@ -17,7 +17,8 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 import pytest  # noqa: E402
 
 from bench import loads, run  # noqa: E402
-from bench.tests.tiny import overrides  # noqa: E402
+from bench.tests.tiny import (LADDER, overrides, patch_count,  # noqa: E402
+                              rungs, tiny_patches)
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in SPEC["workloads"]]
@@ -28,17 +29,25 @@ KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 def test_cell_resolves_by_name(cell):
     c = run.load_cell(cell)
     assert c["chips"] in (1, 4)
-    assert c["cell"]["keep_patches"] <= (c["cfg"]["img_size"]
-                                         // c["cfg"]["patch"]) ** 2
+    assert c["cell"]["keep_patches"] <= patch_count(c["cfg"])
     assert set(c["cell"]["limits"]) == {"max_gap", "mean_gap"}
     for m in c["per_layer"]:
         assert (ROOT / "bench" / "metrics" / f"{m['name']}.py").is_file()
     names = {m["name"] for m in c["end_to_end"]}
     assert "setup_s" in names and len(names) >= 2
     assert c["per_layer"]
+    # a kernel's roofline names a kernel the cell's model declares
+    for m in c["per_layer"]:
+        if m["name"].endswith("_roofline"):
+            assert m["name"][:-len("_roofline")] in c["model"].KERNELS
+    # the Tiny keep is a rung of the ladder over the model's Tiny patches,
+    # all of them exactly where the cell keeps all of its own
+    n_tiny = tiny_patches(cell)
     tiny = overrides(cell)["cell"]["keep_patches"]
-    assert tiny in (4, 8, 12, 16)
-    assert (tiny == 16) == (c["cell"]["keep_patches"] == 196)
+    assert tiny in rungs(n_tiny, c["cell"]["server"].get("bucket_fractions",
+                                                         LADDER))
+    assert (tiny == n_tiny) == (c["cell"]["keep_patches"]
+                                == patch_count(c["cfg"]))
 
 
 def test_every_config_and_traffic_file_is_used_and_valid():
@@ -96,7 +105,9 @@ def test_traffic_loop_runs_in_process(cell, capsys):
     json.dumps(res)
 
 
-def test_traced_run_reports_per_layer_metrics():
+def test_traced_run_reports_per_layer_metrics(tmp_path, monkeypatch):
+    # a trace directory of its own: tests in other workers trace too
+    monkeypatch.setattr(run, "TRACE_DIR", tmp_path)
     cell = "base224-keep33"
     res = run.run_cell(cell, 7, 1.0, True, overrides=overrides(cell),
                        require_tpu=False)
@@ -127,6 +138,8 @@ def test_a_new_cell_is_data_only(tmp_path):
     """A cell added as BENCHMARK.json entry + traffic file + cell file runs
     with no edit to any code."""
     shutil.copytree(ROOT / "bench" / "configs", tmp_path / "bench/configs")
+    shutil.copytree(ROOT / "bench" / "models", tmp_path / "bench/models",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copytree(ROOT / "bench" / "workloads",
                     tmp_path / "bench/workloads")
     shutil.copytree(ROOT / "bench" / "traffic", tmp_path / "bench/traffic")
@@ -160,9 +173,9 @@ def test_serve_mfu_reads_the_untraced_window():
     """The step's share of the peak is taken over the window served at the
     host's own pace, not over the traced stretch after it."""
     from bench import ops, peaks
-    cfg = run.load_cell("base224-keep33")["cfg"]
-    work = run._window_work(cfg, loads.Served(frames=512, scored=64,
-                                              flushes=[(98, 4)] * 128))
+    c = run.load_cell("base224-keep33")
+    work = run._window_work(c["model"], c["cfg"], loads.Served(
+        frames=512, scored=64, flushes=[(98, 4)] * 128))
     pk = peaks.peaks_for("TPU v5 lite")
     ctx = {"peaks": pk, "window_work": work, "chips": 1,
            "served": loads.Served(window_s=2.0),

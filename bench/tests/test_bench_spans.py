@@ -144,7 +144,9 @@ def test_recorded_span_stretch_on_the_cpu(tmp_path):
     assert ring.total() == pytest.approx(1.0, abs=1e-9)
 
 
-def test_traced_run_reports_mgnet_device_share():
+def test_traced_run_reports_mgnet_device_share(tmp_path, monkeypatch):
+    # a trace directory of its own: tests in other workers trace too
+    monkeypatch.setattr(run, "TRACE_DIR", tmp_path)
     cell = "base224-keep33"
     res = run.run_cell(cell, 9, 1.0, True, overrides=overrides(cell),
                        require_tpu=False)
